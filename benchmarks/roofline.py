@@ -60,53 +60,43 @@ def roofline_rows():
 
 
 def repair_collectives():
-    """Lower the layered-repair SPMD program per code and compare the
-    HLO cross-pod collective bytes against the plan's Eq.(3) accounting."""
-    import subprocess
-    import sys
+    """Compile the node-recovery program of each code with one rack per
+    device (an (r, 1) mesh on this process's first r devices) and compare
+    its compiled cross-pod permute bytes with the plan's Eq. (3) count.
 
-    script = r"""
-import os
-os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=9'
-import jax, jax.numpy as jnp, numpy as np, json
-from repro.core.codes import make_code
-from repro.dist.collectives import plan_to_spmd, make_spmd_repair
-from repro.launch.hlo_analysis import parse_collectives
-from jax.sharding import PartitionSpec as P
-mesh = jax.make_mesh((3,3), ('pod','node'), axis_types=(jax.sharding.AxisType.Auto,)*2)
-out = []
-SUB = 1 << 20
-for fam, n, k, r in [('RS',9,6,3), ('MSR',9,6,3), ('DRC',9,6,3), ('RS',9,5,3), ('DRC',9,5,3)]:
-    code = make_code(fam, n, k, r)
-    plan = code.repair_plan(0)
-    spec = plan_to_spmd(code, plan)
-    fn = jax.shard_map(make_spmd_repair(spec), mesh=mesh,
-                       in_specs=P(('pod','node')), out_specs=P(('pod','node')))
-    comp = jax.jit(fn).lower(
-        jax.ShapeDtypeStruct((code.n, code.alpha, SUB), jnp.uint8)).compile()
-    st = parse_collectives(comp.as_text())
-    cross = st.bytes_by_op.get('collective-permute', 0) / (code.alpha * SUB)
-    plan_cross = plan.traffic_blocks()['cross_rack_blocks']
-    out.append((f'{fam}({n},{k},{r})', cross, plan_cross))
-print(json.dumps(out))
-"""
-    proc = subprocess.run(
-        [sys.executable, "-c", script],
-        capture_output=True,
-        text=True,
-        env={**os.environ, "PYTHONPATH": "src"},
-        timeout=900,
-    )
+    Compiles in this process, which holds the devices; raises when there
+    are fewer devices than racks.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.codes import make_code
+    from repro.dist.collectives import node_recovery_program
+    from repro.launch.hlo_analysis import cross_pod_permute_bytes
+    from repro.launch.mesh import make_repair_mesh
+
+    sub = 1 << 20
+    devices = jax.devices()
     rows = []
-    if proc.returncode != 0:
-        return [("repair_hlo/error", 0.0, proc.stderr.strip().splitlines()[-1][:80])]
-    data = json.loads(proc.stdout.strip().splitlines()[-1])
-    for label, hlo_cross, plan_cross in data:
-        rows.append(
-            (
-                f"repair_hlo/{label}",
-                0.0,
-                f"hlo_cross_blocks={hlo_cross:.2f};plan_cross_blocks={plan_cross:.2f}",
-            )
-        )
+    for fam, n, k, r in [("RS", 9, 6, 3), ("MSR", 9, 6, 3), ("DRC", 9, 6, 3),
+                         ("RS", 9, 5, 3), ("DRC", 9, 5, 3),
+                         ("RS", 8, 6, 4), ("DRC", 8, 6, 4)]:
+        if len(devices) < r:
+            raise RuntimeError(
+                f"repair_hlo needs {r} devices for {fam}({n},{k},{r}), "
+                f"found {len(devices)}")
+        code = make_code(fam, n, k, r)
+        mesh = make_repair_mesh(r, n // r, devices[:r])
+        prog, _ = node_recovery_program(code, 0, 1, mesh)
+        x = jax.ShapeDtypeStruct(
+            (1, code.n, code.alpha, sub), jnp.uint8,
+            sharding=NamedSharding(mesh, P(None, ("pod", "node"))))
+        hlo = prog.lower(x).compile().as_text()
+        hlo_cross = cross_pod_permute_bytes(hlo, 1) / (code.alpha * sub)
+        plan_cross = code.repair_plan(0).traffic_blocks()["cross_rack_blocks"]
+        rows.append((
+            f"repair_hlo/{fam}({n},{k},{r})", 0.0,
+            f"hlo_cross_blocks={hlo_cross:.2f};plan_cross_blocks={plan_cross:.2f}",
+        ))
     return rows

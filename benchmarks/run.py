@@ -4,6 +4,9 @@ benches.  Prints ``name,us_per_call,derived`` CSV.
   PYTHONPATH=src python -m benchmarks.run [--only fig6 fig7 ...]
                                           [--trace out/bench_trace.json]
 
+JAX's persistent compilation cache lives in ``$JAX_COMPILATION_CACHE_DIR``
+when it is set, else in ``.jax_cache`` at the repository root.
+
 With ``--trace PATH`` the whole run executes under a `repro.obs` tracer:
 every suite gets a span, the simulator/kernel instrumentation fires, and
 two artifacts are persisted next to the CSV output — ``PATH`` (Chrome
@@ -19,8 +22,11 @@ import sys
 import traceback
 
 from repro import obs
+from repro.compile_cache import use_compile_cache
 
 from . import kernels_bench, paper_tables, roofline
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SUITES = {
     "fig3": paper_tables.fig3_bandwidth,
@@ -47,6 +53,7 @@ def main(argv=None) -> None:
              "and an aggregated summary to PATH's .summary.json sibling",
     )
     args = ap.parse_args(argv)
+    use_compile_cache(os.path.join(REPO, ".jax_cache"))
     names = args.only or list(SUITES)
     print("name,us_per_call,derived")
     failed = []

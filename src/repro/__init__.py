@@ -1,11 +1,1 @@
-"""repro — DoubleR repair layering (arXiv 1704.03696) as a jax system.
-
-Importing any ``repro.*`` module installs the jax version shims
-(``repro.dist.compat``) so code written against the current sharding
-API (``jax.shard_map``, ``jax.set_mesh``, …) also runs on jax 0.4.x.
-The install is hasattr-guarded and idempotent: on a jax that already
-has the APIs it does nothing.
-"""
-from repro.dist import compat as _compat
-
-_compat.install()
+"""repro — DoubleR repair layering (arXiv 1704.03696) as a jax system."""

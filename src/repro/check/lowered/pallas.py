@@ -423,7 +423,7 @@ def mutate_pallas(
         )
     if mutation == "pallas_sum_no_dtype":
         # drop the explicit accumulator dtype of the pack-bits reduction
-        needle = "axis=1, dtype=jnp.uint8"
+        needle = "axis=1, dtype=jnp.int32"
         if needle not in source:
             raise ValueError(f"mutation target {needle!r} not in source")
         return "source", source.replace(needle, "axis=1", 1)

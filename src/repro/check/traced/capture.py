@@ -32,20 +32,20 @@ import numpy as np
 # --------------------------------------------------------------- jaxpr walk
 def _sub_jaxprs(eqn: Any) -> Iterator[Any]:
     """Inner (plain) jaxprs reachable from one equation's params."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for v in eqn.params.values():
         vals = v if isinstance(v, (list, tuple)) else (v,)
         for item in vals:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, Jaxpr):
                 yield item
 
 
 def iter_eqns(jaxpr: Any) -> Iterator[Any]:
     """All equations of a (closed) jaxpr, recursing into sub-jaxprs
-    (pjit, shard_map, scan, cond, pallas_call, ...)."""
+    (jit, shard_map, scan, cond, pallas_call, ...)."""
     inner = getattr(jaxpr, "jaxpr", jaxpr)  # accept ClosedJaxpr or Jaxpr
     for eqn in inner.eqns:
         yield eqn
@@ -232,7 +232,7 @@ def capture_spmd_repair(
 
     from repro.core.codes import make_code
     from repro.dist.collectives import make_spmd_repair, plan_to_spmd
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     code = make_code(family, n, k, r=r)
     plan = code.repair_plan(failed)
@@ -243,7 +243,10 @@ def capture_spmd_repair(
         make_spmd_repair(spec), mesh=mesh,
         in_specs=P(("pod", "node")), out_specs=P(("pod", "node")),
     )
-    x = jax.ShapeDtypeStruct((n, spec.alpha, sub), jnp.uint8)
+    # the input carries the mesh sharding, so the donated buffer can
+    # alias the identically sharded output
+    x = jax.ShapeDtypeStruct((n, spec.alpha, sub), jnp.uint8,
+                             sharding=NamedSharding(mesh, P(("pod", "node"))))
     return _capture(
         f"spmd_repair[{family}({n},{k},{r}) failed={failed}]",
         REPAIR,

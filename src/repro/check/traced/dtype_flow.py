@@ -7,7 +7,7 @@ parities that no shape check can see.  The lowered layer has a
 source-level taint pass (``lowered.pallas.check_gf_dtype``) but it
 stops at function boundaries; here the program is fully inlined into a
 jaxpr, so the taint follows payloads through every call layer —
-``pjit``, ``shard_map``, ``scan``/``while``/``cond`` bodies, Pallas
+``jit``, ``shard_map``, ``scan``/``while``/``cond`` bodies, Pallas
 kernel jaxprs — exactly as XLA sees them.
 
 The lattice: a value is **tainted** when it (transitively) derives from
@@ -51,7 +51,7 @@ _ARITH_PRIMS = frozenset({
 
 # Structural / bitwise: taint flows through unchanged.
 _HIGHER_ORDER = frozenset({
-    "pjit", "closed_call", "core_call", "xla_call", "remat", "checkpoint",
+    "jit", "closed_call", "core_call", "xla_call", "remat", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
 })
 
@@ -73,11 +73,11 @@ def _is_uint8(v: Any) -> bool:
 
 
 def _first_sub_jaxpr(eqn: Any) -> Any | None:
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for key in ("jaxpr", "call_jaxpr"):
         v = eqn.params.get(key)
-        if isinstance(v, (jax.core.ClosedJaxpr, jax.core.Jaxpr)):
+        if isinstance(v, (ClosedJaxpr, Jaxpr)):
             return v
     return None
 
@@ -106,9 +106,9 @@ class _TaintInterp:
         return [self._read(env, v) for v in jaxpr.outvars]
 
     def _read(self, env: dict[Any, bool], v: Any) -> bool:
-        import jax
+        from jax.extend.core import Literal
 
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             return False  # scalar literals (masks, init values) are clean
         return env.get(v, False)
 

@@ -4,8 +4,8 @@ Plan-time linear algebra lives in `repro.core.gf` (numpy).  This module
 executes the resulting matrices against real payload bytes as jitted JAX ops.
 Two interchangeable execution paths:
 
-* ``gf_matmul_jnp`` — pure-jnp mul-table gather + XOR reduce (oracle; runs
-  everywhere, used by tests and small payloads).
+* ``gf_matmul_jnp`` — pure-jnp shift-and-add product on uint8 (runs
+  everywhere; the product of the SPMD repair and checkpoint encode paths).
 * ``repro.kernels.ops.gf_matmul`` — Pallas TPU kernel (bitplane MXU matmul);
   validated against this module in interpret mode.
 """
@@ -31,24 +31,65 @@ def gf_mul(a: jax.Array, b: jax.Array) -> jax.Array:
     return MUL_TABLE[a.astype(jnp.int32), b.astype(jnp.int32)]
 
 
+# rows * k * tile, the work of one step of gf_matmul_jnp: its
+# temporaries stay a few MiB whatever the payload width.
+_TILE_ELEMS = 1 << 22
+
+
+def _xtime(p: jax.Array) -> jax.Array:
+    """Multiply every byte by 2 in GF(2^8) (polynomial 0x11D)."""
+    doubled = p << 1
+    return jnp.where((p & 0x80) != 0, doubled ^ jnp.uint8(0x1D), doubled)
+
+
+def _gf_matmul_tile(m: jax.Array, x: jax.Array) -> jax.Array:
+    # shift-and-add over the 8 bits of each coefficient:
+    #   m ⊗ x = XOR_i bit_i(m) · (2^i ⊗ x)
+    # so a step is selects and XORs on uint8, with no table gather.
+    # One (rows, tile) select per (bit, input row): an XOR-reduce over
+    # a (rows, k, tile) select computes the same bytes, but the TPU
+    # compiler takes about 4x longer over it.
+    m = m.astype(jnp.uint8)
+    p = x.astype(jnp.uint8)  # 2^i ⊗ x at step i
+    out = jnp.zeros_like(p, shape=(m.shape[0], p.shape[1]))
+    for i in range(8):
+        bit = ((m >> i) & 1) != 0  # (rows, k)
+        for j in range(m.shape[1]):
+            out = out ^ jnp.where(bit[:, j:j + 1], p[j:j + 1], jnp.uint8(0))
+        if i < 7:
+            p = _xtime(p)
+    return out
+
+
 @jax.jit
 def gf_matmul_jnp(m: jax.Array, x: jax.Array) -> jax.Array:
     """GF(256) matrix product (rows, k) @ (k, payload) -> (rows, payload).
 
-    XOR-accumulated table products via one gather:
-      prod[r, j, p] = table[m[r, j], x[j, p]]; out[r, p] = XOR_j prod[r, j, p].
+    Shift-and-add: out[r, p] = XOR_j XOR_i bit_i(m[r, j]) · (2^i ⊗ x[j, p]),
+    selects and XORs on uint8 only, where a table gather would
+    widen every payload byte to an int32 index.
 
-    XOR-reduce is expressed as a loop of jnp.bitwise_xor.reduce over axis 1.
+    The payload axis is walked in tiles of ``_TILE_ELEMS // (rows*k)``
+    bytes (a multiple of 128), so temporaries stay bounded at any width.
     """
-    m = m.astype(jnp.int32)
-    x = x.astype(jnp.int32)
-    prod = MUL_TABLE[m[:, :, None], x[None, :, :]]  # (rows, k, payload) uint8
-    return jax.lax.reduce(
-        prod,
-        jnp.uint8(0),
-        lambda a, b: jnp.bitwise_xor(a, b),
-        dimensions=(1,),
-    )
+    rows, k = m.shape
+    payload = x.shape[1]
+    tile = max(128, _TILE_ELEMS // max(rows * k, 1) // 128 * 128)
+    if payload <= tile:
+        return _gf_matmul_tile(m, x)
+
+    def step(i: jax.Array, out: jax.Array) -> jax.Array:
+        # the last tile ends at the payload's end and may overlap the one
+        # before it; columns are independent, so it rewrites equal bytes
+        at = jnp.minimum(i * tile, payload - tile)
+        xs = jax.lax.dynamic_slice_in_dim(x, at, tile, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, _gf_matmul_tile(m, xs), at, axis=1
+        )
+
+    # zeros_like keeps the payload's varying mesh axes inside shard_map
+    out = jnp.zeros_like(x, shape=(rows, payload))
+    return jax.lax.fori_loop(0, -(-payload // tile), step, out)
 
 
 def gf_matvec_bytes(m: np.ndarray | jax.Array, x: jax.Array) -> jax.Array:

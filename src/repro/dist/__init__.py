@@ -12,11 +12,4 @@ Two halves (docs/architecture.md §5):
   ``node`` axis only, relayer→collector transfer as collective-permutes
   across ``pod`` whose compiled bytes equal the plan's cross-rack
   accounting (the Eq. (3) claim, checked in HLO).
-
-Importing this package (or any ``repro.*`` module — see
-``repro/__init__.py``) installs the :mod:`repro.dist.compat` shims so
-the same sources run on jax 0.4.x and current jax.
 """
-from . import compat as _compat
-
-_compat.install()

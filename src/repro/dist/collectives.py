@@ -1,10 +1,12 @@
 """Lower a ``RepairPlan`` to one SPMD program over a ``(pod, node)`` mesh.
 
 The paper's DoubleR workflow (§2.2) maps onto a device mesh with the
-rack structure made explicit: ``pod`` is the rack axis (r racks) and
-``node`` the within-rack axis (w = n/r nodes); device (p, j) holds the
-(alpha, sub) payload of node ``p*w + j``, matching
-``Placement.rack_of``.  The lowering is two-phase:
+rack structure made explicit: ``pod`` is the rack axis and ``node`` the
+within-rack axis.  The stripe is node-major — row ``p*w + j`` is node j
+of rack p, matching ``Placement.rack_of`` — and the mesh's shape picks
+how many of its rows each device holds (:func:`mesh_layout`): one node
+per device on an (r, w) mesh, one rack per device on (r, 1), the whole
+stripe on (1, 1).  The lowering is two-phase:
 
 * :func:`plan_to_spmd` compiles the plan's GF(256) DAG into a *static*
   :class:`SpmdRepairSpec` — stacked per-node NodeEncode matrices,
@@ -14,18 +16,19 @@ rack structure made explicit: ``pod`` is the rack axis (r racks) and
   ``spmd.cross_bytes`` verifier rule exploits.
 * :func:`make_spmd_repair` turns a spec into a ``shard_map`` body:
 
-  - **inner** — NodeEncode then ``all_gather`` over the ``node`` axis
-    *only* (twice when relayers exist: node units, then relayer
-    units), so intra-rack aggregation never crosses a pod boundary;
+  - **inner** — NodeEncode then the rack's pool, assembled from local
+    rows or by ``all_gather`` over the ``node`` axis *only* (twice when
+    relayers exist: node units, then relayer units), so intra-rack
+    aggregation never crosses a pod boundary;
   - **cross** — one ``lax.ppermute`` over ``pod`` per source rack,
     statically sliced to exactly that rack's cross units, so the
     compiled HLO's collective-permute bytes equal
     ``plan.traffic_blocks()["cross_rack_blocks"] * alpha * sub`` — the
     Eq. (3) bound as a property of the *collective schedule*, not just
-    the plan;
-  - **decode** — the collector (device (target_pod, 0), i.e. output
-    row ``target_pod * w``) gathers its canonical unit order and
-    applies the decode matrix.
+    the plan.  Where all racks share one device it is a local ``take``
+    of the same pool rows;
+  - **decode** — the collector (output row ``target_pod * w``) gathers
+    its canonical unit order and applies the decode matrix.
 
 :func:`spmd_repair` runs one stripe; :func:`spmd_node_recovery` runs S
 stripes in a single program with the relayer role rotating per stripe
@@ -37,17 +40,13 @@ symbolic accounting.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Sequence
+from typing import Any, Callable
 
 import numpy as np
 
 from repro import obs
 from repro.core.code_base import ErasureCode
 from repro.core.repair import TARGET, RepairPlan, Send
-
-from . import compat as _compat
-
-_compat.install()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,9 +58,9 @@ class SpmdRepairSpec:
     k: int
     r: int
     alpha: int
-    w: int  # nodes per pod (mesh "node" axis size)
+    w: int  # nodes per rack
     failed: int
-    target_pod: int  # rack of the failed node; collector = (target_pod, 0)
+    target_pod: int  # rack of the failed node; collector = row target_pod*w
     rel_idx: np.ndarray  # (num_relayers,) int32 — relayer node ids
     node_mats: np.ndarray  # (n, nu, alpha) uint8 — stacked NodeEncode rows
     relayer_mats: np.ndarray  # (n, ru, alpha + w*nu) uint8, pool-indexed
@@ -240,13 +239,36 @@ def plan_to_spmd(code: ErasureCode, plan: RepairPlan) -> SpmdRepairSpec:
     )
 
 
-def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
-    """Build the shard_map body: (1, alpha, sub) per device in/out.
+def mesh_layout(spec: SpmdRepairSpec, pods: int, nodes: int) -> tuple[int, int]:
+    """(racks, nodes per rack) each device holds on a (pods, nodes) mesh.
 
-    The returned function must run inside ``shard_map`` over a mesh
-    with axes ``("pod", "node")`` of sizes (spec.r, spec.w).  Output
-    row ``target_pod * w`` (device (target_pod, 0)) carries the
-    reconstructed payload; every other row is zero.
+    Three layouts, chosen by the mesh's shape alone:
+
+    * ``(r, w)`` — one storage node per device;
+    * ``(r, 1)`` — one rack per device, its w nodes rows of the block;
+    * ``(1, 1)`` — the whole stripe on one device.
+    """
+    if pods not in (spec.r, 1) or nodes not in (spec.w, 1) or (
+        pods == 1 and nodes != 1
+    ):
+        raise ValueError(
+            f"mesh axes {{'pod': {pods}, 'node': {nodes}}} fit no layout of "
+            f"the code's racks (r={spec.r}, w={spec.w}): use (r, w), (r, 1) "
+            f"or (1, 1)"
+        )
+    return spec.r // pods, spec.w // nodes
+
+
+def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
+    """Build the shard_map body over a ``("pod", "node")`` mesh.
+
+    Each device holds ``(racks * nodes, alpha, sub)`` of the node-major
+    stripe, as :func:`mesh_layout` reads off the mesh's axis sizes.
+    Where a rack's nodes share a device its pool is assembled locally,
+    else by ``all_gather`` over ``node``.  Where racks share a device
+    the cross stage is a local ``take`` of the same pool rows the
+    ``ppermute`` over ``pod`` would ship.  Output row ``target_pod * w``
+    carries the reconstructed payload; every other row is zero.
     """
     import jax
     import jax.numpy as jnp
@@ -254,64 +276,96 @@ def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
     from repro.core.gf_jax import gf_matmul_jnp
 
     w, nu, ru = spec.w, spec.nu, spec.ru
-    node_mats = jnp.asarray(spec.node_mats)
-    relayer_mats = jnp.asarray(spec.relayer_mats) if ru else None
     # declared schedule; plan_to_spmd never emits a (q, q) self-send and
     # the lowered verifier rule lowered.spmd.permute-partial proves it
-    cross = [
-        (q, jnp.asarray(np.asarray(rows, np.int32)))
-        for q, dst, rows in spec.permute_steps()
-        if q != dst
-    ]
-    target_idx = jnp.asarray(np.asarray(spec.target_idx, np.int32))
+    cross = [(q, rows) for q, dst, rows in spec.permute_steps() if q != dst]
     decode = jnp.asarray(spec.decode)
+    collector = spec.target_pod * w
+
+    def take(pool: Any, rows: tuple[int, ...]) -> Any:
+        # static row picks as slices: a gather of payload-wide rows is
+        # split into thousands of pieces by the TPU compiler
+        return jnp.concatenate([pool[i:i + 1] for i in rows], axis=0)
+
+    def encode(mats: np.ndarray, node: Any, operand: Any) -> Any:
+        # a node id is a Python int where every device holds its rack;
+        # such a node with nothing to send then computes nothing
+        if not isinstance(node, int):
+            m = jax.lax.dynamic_index_in_dim(jnp.asarray(mats), node, 0,
+                                             keepdims=False)
+            return gf_matmul_jnp(m, operand)
+        if not mats[node].any():
+            return jnp.zeros_like(operand, shape=(mats.shape[1],
+                                                  operand.shape[1]))
+        return gf_matmul_jnp(jnp.asarray(mats[node]), operand)
 
     def repair(x: Any) -> Any:
-        p = jax.lax.axis_index("pod")
-        j = jax.lax.axis_index("node")
-        dev = p * w + j  # global node id of this device
-        own = x[0]  # (alpha, sub)
+        pods = jax.lax.axis_size("pod")
+        nodes = jax.lax.axis_size("node")
+        racks, per_rack = mesh_layout(spec, pods, nodes)
+        p = jax.lax.axis_index("pod") if pods > 1 else 0
+        j = jax.lax.axis_index("node") if nodes > 1 else 0
 
-        # inner: NodeEncode, then aggregate over the node axis only
-        a = jax.lax.dynamic_index_in_dim(node_mats, dev, 0, keepdims=False)
-        y = gf_matmul_jnp(a, own)  # (nu, sub)
-        pool = jax.lax.all_gather(y, "node").reshape(w * nu, -1)
-        if relayer_mats is not None:
-            # RelayerEncode consumes [own subblocks ++ rack pool]; its
-            # units are pooled in-rack too (rows w*nu .. w*nu + w*ru)
-            rm = jax.lax.dynamic_index_in_dim(relayer_mats, dev, 0,
-                                              keepdims=False)
-            z = gf_matmul_jnp(rm, jnp.concatenate([own, pool], axis=0))
-            zf = jax.lax.all_gather(z, "node").reshape(w * ru, -1)
-            pool = jnp.concatenate([pool, zf], axis=0)
+        def own(a: int, b: int) -> Any:
+            # node b of local rack a; row indexing, as a reshape of the
+            # uint8 block compiles in time that grows with its size
+            return x[a * per_rack + b]
 
-        # cross: each source pod ships exactly its scheduled units to
-        # the target pod — one collective-permute per source pod, so
-        # compiled cross-pod bytes == sum(len(rows)) * sub
-        recvs = [
-            jax.lax.ppermute(
-                jnp.take(pool, rows, axis=0), "pod",
-                [(q, spec.target_pod)],
-            )
-            for q, rows in cross
-        ]
+        def rack_pool(units: list[Any]) -> Any:
+            # a rack's units, node-major: local rows or all_gather over
+            # the node axis only, so aggregation never crosses a pod
+            if per_rack == w:
+                return jnp.concatenate(units, axis=0)
+            return jax.lax.all_gather(units[0], "node", tiled=True)
+
+        # inner: NodeEncode, then RelayerEncode over [own ++ rack pool];
+        # relayer units are pooled in-rack too (rows w*nu .. w*nu + w*ru)
+        ids = [[(p * racks + a) * w + j * per_rack + b
+                for b in range(per_rack)] for a in range(racks)]
+        pools = []
+        for a in range(racks):
+            pool = rack_pool([encode(spec.node_mats, i, own(a, b))
+                              for b, i in enumerate(ids[a])])
+            if ru:
+                zs = [encode(spec.relayer_mats, i,
+                             jnp.concatenate([own(a, b), pool], axis=0))
+                      for b, i in enumerate(ids[a])]
+                pool = jnp.concatenate([pool, rack_pool(zs)], axis=0)
+            pools.append(pool)
+
+        # cross: each source rack ships exactly its scheduled units to
+        # the target rack — one collective-permute per source pod when
+        # racks are devices, so compiled cross-pod bytes ==
+        # sum(len(rows)) * sub; a local take when they share one
+        if racks == 1:
+            pool = pools[0]
+            recvs = [
+                jax.lax.ppermute(take(pool, rows), "pod",
+                                 [(q, spec.target_pod)])
+                for q, rows in cross
+            ]
+        else:
+            pool = pools[spec.target_pod]
+            recvs = [take(pools[q], rows) for q, rows in cross]
         pool2 = jnp.concatenate([pool, *recvs], axis=0) if recvs else pool
 
-        # decode on the collector; other devices emit zeros
-        rec = gf_matmul_jnp(decode, jnp.take(pool2, target_idx, axis=0))
-        is_collector = jnp.logical_and(p == spec.target_pod, j == 0)
-        return jnp.where(is_collector, rec, jnp.zeros_like(rec))[None]
+        # decode; only the collector's row keeps it
+        rec = gf_matmul_jnp(decode, take(pool2, spec.target_idx))
+        zero = jnp.zeros_like(rec)
+        return jnp.stack([
+            (rec if i == collector else zero) if isinstance(i, int)
+            else jnp.where(i == collector, rec, zero)
+            for row in ids for i in row
+        ])
 
     return repair
 
 
 def _check_mesh(spec: SpmdRepairSpec, mesh: Any) -> None:
     shape = dict(mesh.shape)
-    want = {"pod": spec.r, "node": spec.w}
-    if shape != want:
-        raise ValueError(
-            f"mesh axes {shape} do not match the code's rack layout {want}"
-        )
+    if set(shape) != {"pod", "node"}:
+        raise ValueError(f"mesh axes {shape} are not ('pod', 'node')")
+    mesh_layout(spec, shape["pod"], shape["node"])
 
 
 def _record_schedule(spec: SpmdRepairSpec, sub_bytes: int) -> None:
@@ -369,6 +423,46 @@ def spmd_repair(
     return out, spec
 
 
+# One jitted program per (code, failed node, stripe count, mesh).
+_RECOVERY_PROGRAMS: dict[tuple[str, int, int, Any], Any] = {}
+
+
+def node_recovery_program(
+    code: ErasureCode, failed: int, n_stripes: int, mesh: Any
+) -> tuple[Any, list[SpmdRepairSpec]]:
+    """The jitted ``(S, n, alpha, sub) -> (S, n, alpha, sub)`` program
+    :func:`spmd_node_recovery` runs, plus its per-stripe specs.
+
+    Stripe s uses ``repair_plan(failed, rotation=s)``.  Built once per
+    (code, failed, S, mesh); ``.lower(...)`` on the result gives the
+    compiled module for byte and memory checks.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    specs = [plan_to_spmd(code, code.repair_plan(failed, rotation=s))
+             for s in range(n_stripes)]
+    for spec in specs:
+        _check_mesh(spec, mesh)
+    key = (repr(code), failed, n_stripes, mesh)
+    prog = _RECOVERY_PROGRAMS.get(key)
+    if prog is None:
+        bodies = [make_spmd_repair(spec) for spec in specs]
+
+        def body(x: Any) -> Any:  # (S, racks*nodes, alpha, sub) per device
+            return jnp.stack([fn(x[s]) for s, fn in enumerate(bodies)],
+                             axis=0)
+
+        prog = jax.jit(jax.shard_map(
+            body, mesh=mesh,
+            in_specs=P(None, ("pod", "node")),
+            out_specs=P(None, ("pod", "node")),
+        ))
+        _RECOVERY_PROGRAMS[key] = prog
+    return prog, specs
+
+
 def spmd_node_recovery(
     code: ErasureCode, failed: int, payloads: Any, mesh: Any
 ) -> tuple[Any, list[SpmdRepairSpec]]:
@@ -379,32 +473,9 @@ def spmd_node_recovery(
     across the helper nodes of each remote rack (paper §5.2: node-level
     repair load balance).  Returns ((S, n, alpha, sub), specs).
     """
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
     n_stripes = int(payloads.shape[0])
-    specs: list[SpmdRepairSpec] = []
-    bodies: list[Callable[[Any], Any]] = []
-    for s in range(n_stripes):
-        spec = plan_to_spmd(code, code.repair_plan(failed, rotation=s))
-        _check_mesh(spec, mesh)
-        specs.append(spec)
-        bodies.append(make_spmd_repair(spec))
+    prog, specs = node_recovery_program(code, failed, n_stripes, mesh)
     sub_bytes = int(payloads.shape[-1])
-
-    def body(x: Any) -> Any:  # (S, 1, alpha, sub) per device
-        return jnp.stack([fn(x[s]) for s, fn in enumerate(bodies)], axis=0)
-
-    fn = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=P(None, ("pod", "node")),
-        out_specs=P(None, ("pod", "node")),
-    )
-    relayer_loads: dict[str, int] = {}
-    for spec in specs:
-        for rel in spec.rel_idx.tolist():
-            relayer_loads[str(rel)] = relayer_loads.get(str(rel), 0) + 1
     with obs.span("repair.spmd_node_recovery", cat="repair", failed=failed,
                   family=specs[0].family if specs else "", stripes=n_stripes,
                   distinct_relayer_sets=len(
@@ -412,7 +483,7 @@ def spmd_node_recovery(
                   )):
         for spec in specs:
             _record_schedule(spec, sub_bytes)
-        out = jax.jit(fn)(payloads)
+        out = prog(payloads)
     return out, specs
 
 

@@ -31,9 +31,7 @@ from typing import Any, Iterator, Mapping, Sequence
 
 import jax
 
-from . import compat as _compat
-
-_compat.install()
+from repro.models.common import ambient_mesh
 
 Names = Sequence[str | None]
 
@@ -190,18 +188,6 @@ def current_rules() -> Rules:
 
 
 # ------------------------------------------------------------ constraints
-def _ambient_mesh() -> Any:
-    """Duplicate of models.common.ambient_mesh, kept here to avoid a
-    dist ↔ models import cycle (constrain imports this module)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    mesh = get()
-    if mesh is None or getattr(mesh, "empty", False):
-        return None
-    return mesh
-
-
 _WARNED_NO_MESH = [False]
 
 
@@ -225,7 +211,7 @@ def logical_constraint(x: jax.Array, names: Names) -> jax.Array:
     there is no ambient mesh, and outside of tracing — eager arrays are
     already placed.
     """
-    mesh = _ambient_mesh()
+    mesh = ambient_mesh()
     if mesh is None:
         if _RULES_STACK:
             _warn_rules_without_mesh()

@@ -109,11 +109,14 @@ def gf_matmul_geometry(r: int, k: int, b: int, block_b: int) -> KernelGeometry:
 
 def _gf_bitplane_kernel(mb_ref, x_ref, o_ref, *, k: int, r: int):
     """One grid step: o[:, tile] = pack( (mb @ unpack(x[:, tile])) & 1 )."""
-    x = x_ref[...]  # (k, tb) uint8
-    shifts = jnp.arange(8, dtype=jnp.uint8)
+    # Shifts and the pack reduction run in int32: the TPU compiler
+    # reduces no unsigned integers.
+    x = x_ref[...].astype(jnp.int32)  # (k, tb)
+    tb = x.shape[-1]
+    shifts = jnp.arange(8, dtype=jnp.int32)
     # unpack to bitplanes, row order (byte j, bit i) -> row 8j+i
-    xb = (x[:, None, :] >> shifts[None, :, None]) & jnp.uint8(1)  # (k, 8, tb)
-    xb = xb.reshape(8 * k, x.shape[-1]).astype(jnp.int8)
+    xb = (x[:, None, :] >> shifts[None, :, None]) & 1  # (k, 8, tb)
+    xb = xb.reshape(8 * k, tb).astype(jnp.int8)
     mb = mb_ref[...]  # (8r, 8k) int8
     acc = jax.lax.dot_general(
         mb,
@@ -121,8 +124,9 @@ def _gf_bitplane_kernel(mb_ref, x_ref, o_ref, *, k: int, r: int):
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )  # (8r, tb) int32
-    bits = (acc & 1).astype(jnp.uint8).reshape(r, 8, x.shape[-1])
-    o_ref[...] = jnp.sum(bits << shifts[None, :, None], axis=1, dtype=jnp.uint8)
+    bits = (acc & 1).astype(jnp.uint8).reshape(r, 8, tb)
+    packed = jnp.sum(bits << shifts[None, :, None], axis=1, dtype=jnp.int32)
+    o_ref[...] = packed.astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))

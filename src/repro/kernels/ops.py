@@ -1,13 +1,14 @@
-"""Jitted public wrapper around the Pallas GF(2^8) matmul kernel.
+"""Public wrapper around the Pallas GF(2^8) matmul kernel.
 
-``gf_matmul(m, x)`` is the one entry point the rest of the framework uses
-(checkpoint encode/repair, the storage simulator's compute model, the
-benchmarks).  It
+``gf_matmul(m, x)`` is the kernel's entry point (benchmarks, tests, the
+verifier and the chip smoke run call it).  It
 
 * bit-expands the GF(256) coding matrix host-side (cached by content),
 * pads the payload byte axis to the chosen lane-aligned tile,
-* dispatches the Pallas kernel (interpret=True automatically off-TPU),
-* falls back to the pure-jnp oracle for payloads too small to tile.
+* runs the Pallas kernel compiled, or in interpret mode when the caller
+  passes ``interpret=True`` (the CPU tests do),
+* takes the jnp table product for payloads narrower than one lane tile
+  (chosen by shape, on every backend).
 """
 from __future__ import annotations
 
@@ -24,10 +25,6 @@ from .gf_matmul import gf_matmul_pallas
 from .ref import gf_matmul_ref
 
 _LANE = 128
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.lru_cache(maxsize=4096)
@@ -59,16 +56,16 @@ def gf_matmul(
     x: jax.Array,
     *,
     block_b: int | None = None,
-    force_kernel: bool = False,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """GF(256) coding product: (R, K) @ (K, B) -> (R, B) uint8.
 
     Under an active `repro.obs` tracer every invocation records a
     ``kernel.gf_matmul`` span with wall-clock and achieved GB/s (payload
     in + out bytes; the timing blocks on the result, so traced runs are
-    synchronous).  With tracing off the only extra work is one global
-    read — the dispatch path is untouched.
+    synchronous) and ``path`` naming what ran: ``pallas``,
+    ``pallas_interpret`` or ``ref``.  With tracing off the only extra
+    work is one global read — the dispatch path is untouched.
     """
     m_np = np.asarray(m, dtype=np.uint8)
     r, k = m_np.shape
@@ -76,17 +73,18 @@ def gf_matmul(
     if x.ndim != 2 or x.shape[0] != k:
         raise ValueError(f"payload {x.shape} does not match matrix {m_np.shape}")
     b = x.shape[1]
-    if interpret is None:
-        interpret = not _on_tpu()
     tracer = obs.current()
     if tracer is None:
-        return _dispatch(m_np, x, r, k, b, block_b, force_kernel, interpret)
+        return _dispatch(m_np, x, r, k, b, block_b, interpret)
     t0 = time.perf_counter()
-    y = _dispatch(m_np, x, r, k, b, block_b, force_kernel, interpret)
+    y = _dispatch(m_np, x, r, k, b, block_b, interpret)
     # traced timing must observe the finished result: sync is the point
     jax.block_until_ready(y)  # check: ignore[host-sync]
     dt = max(time.perf_counter() - t0, 1e-9)
-    path = "pallas" if (b >= _LANE and _on_tpu()) or force_kernel else "ref"
+    if b < _LANE:
+        path = "ref"
+    else:
+        path = "pallas_interpret" if interpret else "pallas"
     moved = (k + r) * b  # payload bytes in + out
     tracer.record_span("kernel.gf_matmul", dt, cat="kernel", track="kernel",
                        at_s=tracer.now_us() / 1e6 - dt,
@@ -104,13 +102,9 @@ def _dispatch(
     k: int,
     b: int,
     block_b: int | None,
-    force_kernel: bool,
     interpret: bool,
 ) -> jax.Array:
-    # Off-TPU the Pallas kernel runs in (slow, python-level) interpret
-    # mode — it exists for correctness validation; the log/exp oracle is
-    # the fast CPU path.  On TPU the kernel is the fast path.
-    if (b < _LANE or not _on_tpu()) and not force_kernel:
+    if b < _LANE:  # narrower than one lane tile: nothing to tile
         return gf_matmul_ref(jnp.asarray(m_np), x)
     tb = block_b or choose_block_b(k, r)
     tb = min(tb, max(_LANE, (b // _LANE) * _LANE))
@@ -122,8 +116,10 @@ def _dispatch(
     return y[:, :b] if pad else y
 
 
-def encode_payload(generator: np.ndarray, data: jax.Array) -> jax.Array:
+def encode_payload(
+    generator: np.ndarray, data: jax.Array, *, interpret: bool = False
+) -> jax.Array:
     """Systematic encode: only compute the parity rows on the data path."""
     ka = generator.shape[1]
-    parity = gf_matmul(generator[ka:], data)
+    parity = gf_matmul(generator[ka:], data, interpret=interpret)
     return jnp.concatenate([data, parity], axis=0)

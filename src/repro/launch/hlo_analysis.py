@@ -83,7 +83,12 @@ def parse_permutes(hlo_text: str) -> list[PermuteInstr]:
             pairs = tuple(
                 (int(s), int(d)) for s, d in _PAIR_RE.findall(pm.group(1))
             )
-        out.append(PermuteInstr(nbytes=_shape_bytes(m.group(1)), pairs=pairs))
+        shape = m.group(1)
+        if shape.startswith("("):
+            # the TPU's async start returns (operand, result, contexts...):
+            # the permute moves one buffer, the result
+            shape = "{}[{}]".format(*_SHAPE_RE.findall(shape)[1])
+        out.append(PermuteInstr(nbytes=_shape_bytes(shape), pairs=pairs))
     return out
 
 

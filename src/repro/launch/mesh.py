@@ -8,6 +8,7 @@ module never touches jax device state.  The single-pod mesh is one
 from __future__ import annotations
 
 import jax
+import numpy as np
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,11 +19,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     )
 
 
-def make_repair_mesh(r: int, w: int):
-    """Mesh for the layered-repair SPMD program: r pods x w nodes."""
-    return jax.make_mesh(
-        (r, w), ("pod", "node"), axis_types=(jax.sharding.AxisType.Auto,) * 2
-    )
+def make_repair_mesh(r: int, w: int, devices):
+    """``("pod", "node")`` mesh for the layered-repair SPMD program of a
+    code with r racks of w nodes, over exactly ``devices``.
+
+    The device count picks the layout: r*w devices hold one node each,
+    r devices one rack each, and one device the whole stripe.
+    """
+    n = len(devices)
+    shape = {r * w: (r, w), r: (r, 1), 1: (1, 1)}.get(n)
+    if shape is None:
+        raise ValueError(
+            f"{n} devices fit no layout of {r} racks x {w} nodes: "
+            f"give {r * w}, {r} or 1")
+    return jax.sharding.Mesh(np.asarray(devices).reshape(shape), ("pod", "node"))
 
 
 # Hardware constants (TPU v5e) for the roofline terms.

@@ -113,7 +113,7 @@ def attention(
     causal: bool = True,
     chunk: int = 512,
     cross_kv: tuple[jax.Array, jax.Array] | None = None,
-    use_flash: bool | None = None,
+    use_flash: bool = False,
 ) -> jax.Array:
     """Full-sequence attention (training / prefill)."""
     b, s, _ = x.shape
@@ -132,16 +132,14 @@ def attention(
         k, v = cross_kv
         causal = False
     q = constrain(q, "batch", "seq", "heads", None)
-    if use_flash is None:
-        use_flash = jax.default_backend() == "tpu"
     if (
         use_flash
         and cross_kv is None
         and s % 256 == 0
         and k.shape[1] % 256 == 0
     ):
-        # Pallas flash kernel (forward hot path on TPU); the pure-JAX
-        # chunked path remains the differentiable training default.
+        # the Pallas flash kernel only when the caller asks for it; the
+        # pure-JAX chunked path is the differentiable default everywhere
         from repro.kernels.flash_attention import flash_attention
 
         out = flash_attention(q, k, v, causal=causal)

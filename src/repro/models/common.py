@@ -123,17 +123,9 @@ def param_bytes(tree: Params) -> int:
 
 
 def ambient_mesh():
-    """The ambient abstract mesh, or None when there isn't one.
-
-    jax >= 0.5 exposes ``jax.sharding.get_abstract_mesh()`` (set via
-    use_mesh/set_mesh); older jax has no ambient-mesh context at all, so
-    callers must take their single-process path.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    mesh = get()
-    return None if (mesh is None or mesh.empty) else mesh
+    """The ambient abstract mesh set by ``jax.set_mesh``, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def constrain(x: jax.Array, *names: str | None) -> jax.Array:

@@ -293,6 +293,73 @@ def test_spmd_node_recovery_rotates_relayers():
     assert "OK" in out
 
 
+@pytest.mark.parametrize("family", ["DRC", "RS"])
+@pytest.mark.parametrize("pods, n, k, r", [(1, 9, 6, 3), (4, 8, 6, 4)],
+                         ids=["stripe_on_one_device", "rack_per_device"])
+def test_spmd_repair_device_layouts(pods, n, k, r, family):
+    """The layouts a chip host can hold: the whole stripe on one device
+    (pod = node = 1) and one rack per device (pod = r, node = 1).  The
+    rebuilt block is byte-exact against the encoded payload and numpy
+    ``RepairPlan.execute``, the output stays node-major with the rebuilt
+    block on row ``target_pod * w``, and the program is the one every
+    layout shares."""
+    out = run_sub(
+        f"""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.core.codes import make_code
+        from repro.dist.collectives import spmd_node_recovery, spmd_repair
+        from repro.launch.mesh import make_repair_mesh
+        pods, n, k, r = {pods}, {n}, {k}, {r}
+        mesh = make_repair_mesh(r, n // r, jax.devices()[:pods])
+        assert dict(mesh.shape) == {{'pod': pods, 'node': 1}}
+        code = make_code('{family}', n, k, r)
+        rng = np.random.default_rng(n * 10 + pods)
+        stripes = [code.encode(rng.integers(0, 256, (code.k * code.alpha, 256),
+                                            dtype=np.uint8)) for _ in range(3)]
+        for failed in (0, n - 1):
+            out, spec = spmd_repair(code, failed, jnp.asarray(np.stack(stripes[0])),
+                                    mesh)
+            out = np.asarray(out)
+            want = code.repair_plan(failed).execute(
+                {{i: p for i, p in enumerate(stripes[0]) if i != failed}})
+            row = spec.target_pod * spec.w
+            assert np.array_equal(out[row], stripes[0][failed]), failed
+            assert np.array_equal(out[row], want), failed
+            assert not np.delete(out, row, axis=0).any(), failed
+        x = jnp.asarray(np.stack([np.stack(s) for s in stripes]))
+        out, specs = spmd_node_recovery(code, 0, x, mesh)
+        for s, sp in enumerate(specs):
+            assert np.array_equal(np.asarray(out)[s, sp.target_pod * sp.w],
+                                  stripes[s][0]), s
+        print('OK')
+        """,
+        devices=4,
+    )
+    assert "OK" in out
+
+
+def test_spmd_repair_rejects_mesh_without_layout():
+    """A mesh whose axes match none of (r, w), (r, 1), (1, 1) is refused
+    before anything compiles."""
+    from repro.core.codes import make_code
+    from repro.dist.collectives import mesh_layout, plan_to_spmd
+
+    code = make_code("DRC", 9, 6, 3)
+    spec = plan_to_spmd(code, code.repair_plan(0))
+    assert mesh_layout(spec, 3, 3) == (1, 1)
+    assert mesh_layout(spec, 3, 1) == (1, 3)
+    assert mesh_layout(spec, 1, 1) == (3, 3)
+    for pods, nodes in [(2, 1), (1, 3), (3, 2)]:
+        with pytest.raises(ValueError, match="fit no layout"):
+            mesh_layout(spec, pods, nodes)
+    from repro.launch.mesh import make_repair_mesh
+
+    with pytest.raises(ValueError, match="fit no layout"):
+        make_repair_mesh(3, 3, jax.devices()[:1] * 2)
+    assert dict(make_repair_mesh(3, 3, jax.devices()[:1]).shape) == {
+        "pod": 1, "node": 1}
+
+
 def test_moe_tp_with_model_sharded_tokens():
     """TP experts + sequence-parallel tokens (the grok train layout):
     partial-F outputs must be combined per token, not across different
@@ -325,92 +392,6 @@ def test_moe_tp_with_model_sharded_tokens():
         devices=8,
     )
     assert "OK" in out
-
-
-# ------------------------------------------------------------- compat shims
-def test_compat_native_branch_leaves_modern_jax_untouched(monkeypatch):
-    """On a jax that already exposes the symbols (>= 0.5), install() must
-    not replace them — upgrading jax silently switches to native impls."""
-    from repro.dist import compat
-
-    native_axis_type = object()
-    native_set_mesh = object()
-    native_shard_map = object()
-    native_typeof = object()
-    native_pvary = object()
-
-    def native_make_mesh(axis_shapes, axis_names, *, axis_types=None):
-        return "native-mesh"
-
-    monkeypatch.setattr(jax.sharding, "AxisType", native_axis_type,
-                        raising=False)
-    monkeypatch.setattr(jax, "set_mesh", native_set_mesh, raising=False)
-    monkeypatch.setattr(jax, "shard_map", native_shard_map, raising=False)
-    monkeypatch.setattr(jax, "typeof", native_typeof, raising=False)
-    monkeypatch.setattr(jax.lax, "pvary", native_pvary, raising=False)
-    monkeypatch.setattr(jax, "make_mesh", native_make_mesh, raising=False)
-
-    compat.install()
-
-    assert jax.sharding.AxisType is native_axis_type
-    assert jax.set_mesh is native_set_mesh
-    assert jax.shard_map is native_shard_map
-    assert jax.typeof is native_typeof
-    assert jax.lax.pvary is native_pvary
-    assert jax.make_mesh is native_make_mesh  # has axis_types: kept
-
-
-def test_compat_shim_branch_backfills_04x_jax(monkeypatch):
-    """With the modern symbols absent (jax 0.4.x), install() must
-    backfill working shims."""
-    import jax.numpy as jnp
-
-    from repro.dist import compat
-
-    for mod, name in [
-        (jax.sharding, "AxisType"),
-        (jax, "set_mesh"),
-        (jax.sharding, "get_abstract_mesh"),
-        (jax, "shard_map"),
-        (jax, "typeof"),
-        (jax.lax, "pvary"),
-        (jax, "make_mesh"),
-    ]:
-        monkeypatch.delattr(mod, name, raising=False)
-
-    compat.install()
-
-    # AxisType enum stand-in
-    assert jax.sharding.AxisType.Auto is not None
-
-    # set_mesh maintains the ambient stack; get_abstract_mesh reads it
-    assert compat.ambient_mesh() is None
-    marker = FakeMesh({"i": 1})
-    with jax.set_mesh(marker) as m:
-        assert m is marker
-        assert jax.sharding.get_abstract_mesh() is marker
-    assert compat.ambient_mesh() is None
-
-    # typeof returns an aval carrying shape/dtype
-    aval = jax.typeof(jnp.ones((2, 3), jnp.float32))
-    assert tuple(aval.shape) == (2, 3) and aval.dtype == jnp.float32
-
-    # pvary is the value-level identity without the vma system
-    x = jnp.arange(4)
-    assert jax.lax.pvary(x, ("i",)) is x
-
-    # make_mesh accepts and drops axis_types
-    mesh = jax.make_mesh((1,), ("i",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-    assert dict(mesh.shape) == {"i": 1}
-
-    # shard_map shim swallows check_vma and runs on a concrete mesh
-    real_mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("i",))
-    P = jax.sharding.PartitionSpec
-    f = jax.shard_map(lambda a: a * 2, mesh=real_mesh,
-                      in_specs=P(), out_specs=P(), check_vma=True)
-    np.testing.assert_array_equal(np.asarray(f(jnp.arange(3))),
-                                  np.arange(3) * 2)
 
 
 if __name__ == "__main__":

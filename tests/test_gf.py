@@ -151,6 +151,18 @@ def test_jax_matmul_matches_numpy():
     np.testing.assert_array_equal(got, want)
 
 
+def test_jax_matmul_tiled_matches_numpy(monkeypatch):
+    """A payload wider than one tile: 1000 bytes in tiles of 256, so the
+    loop runs four steps and the last tile overlaps the one before it."""
+    monkeypatch.setattr(gf_jax, "_TILE_ELEMS", 5 * 7 * 256)
+    rng = np.random.default_rng(5)
+    m = rng.integers(0, 256, size=(5, 7), dtype=np.uint8)
+    x = rng.integers(0, 256, size=(7, 1000), dtype=np.uint8)
+    got = np.asarray(gf_jax.gf_matmul_jnp(gf_jax.jnp.asarray(m),
+                                          gf_jax.jnp.asarray(x)))
+    np.testing.assert_array_equal(got, gf.gf_matmul(m, x))
+
+
 def test_bits_roundtrip():
     rng = np.random.default_rng(4)
     x = rng.integers(0, 256, size=(3, 17), dtype=np.uint8)

@@ -33,7 +33,7 @@ SHAPES = [
 def test_kernel_matches_oracle(r, k, b):
     rng = np.random.default_rng(r * 1000 + k * 10 + b)
     m, x = _rand(rng, r, k, b)
-    got = np.asarray(gf_matmul(m, jnp.asarray(x), force_kernel=True))
+    got = np.asarray(gf_matmul(m, jnp.asarray(x), interpret=True))
     want = np.asarray(gf_matmul_ref(jnp.asarray(m), jnp.asarray(x)))
     np.testing.assert_array_equal(got, want)
     # and both match the plan-time numpy path
@@ -54,7 +54,7 @@ def test_kernel_block_shapes(block_b):
 def test_unaligned_payload_padding():
     rng = np.random.default_rng(5)
     m, x = _rand(rng, 3, 6, 333)  # not a multiple of 128
-    got = np.asarray(gf_matmul(m, jnp.asarray(x), force_kernel=True))
+    got = np.asarray(gf_matmul(m, jnp.asarray(x), interpret=True))
     np.testing.assert_array_equal(got, gfnp.gf_matmul(m, x))
 
 
@@ -75,7 +75,7 @@ def test_small_payload_fallback():
 def test_kernel_property_random(r, k, b, seed):
     rng = np.random.default_rng(seed)
     m, x = _rand(rng, r, k, b)
-    got = np.asarray(gf_matmul(m, jnp.asarray(x), force_kernel=True))
+    got = np.asarray(gf_matmul(m, jnp.asarray(x), interpret=True))
     np.testing.assert_array_equal(got, gfnp.gf_matmul(m, x))
 
 
@@ -83,9 +83,9 @@ def test_linearity_over_payload():
     rng = np.random.default_rng(7)
     m, x = _rand(rng, 4, 8, 256)
     y = rng.integers(0, 256, size=x.shape, dtype=np.uint8)
-    lhs = np.asarray(gf_matmul(m, jnp.asarray(x ^ y), force_kernel=True))
-    rhs = np.asarray(gf_matmul(m, jnp.asarray(x), force_kernel=True)) ^ np.asarray(
-        gf_matmul(m, jnp.asarray(y), force_kernel=True)
+    lhs = np.asarray(gf_matmul(m, jnp.asarray(x ^ y), interpret=True))
+    rhs = np.asarray(gf_matmul(m, jnp.asarray(x), interpret=True)) ^ np.asarray(
+        gf_matmul(m, jnp.asarray(y), interpret=True)
     )
     np.testing.assert_array_equal(lhs, rhs)
 
@@ -96,7 +96,7 @@ def test_encode_payload_systematic():
     code = DRCFamily1(9, 6)
     rng = np.random.default_rng(8)
     data = rng.integers(0, 256, size=(code.k * code.alpha, 256), dtype=np.uint8)
-    coded = np.asarray(encode_payload(code.generator, jnp.asarray(data)))
+    coded = np.asarray(encode_payload(code.generator, jnp.asarray(data), interpret=True))
     np.testing.assert_array_equal(coded[: data.shape[0]], data)
     want = gfnp.gf_matmul(code.generator, data)
     np.testing.assert_array_equal(coded, want)

@@ -235,6 +235,24 @@ def test_cross_pod_permute_bytes_counts_cross_only():
     assert cross_pod_permute_bytes(_HLO, w=2) == 512 + 256
 
 
+# the TPU compiler's async form: the start returns (operand, result,
+# u32 contexts), and the permute moves the result once
+_HLO_TPU_ASYNC = """\
+ENTRY %main {
+  %collective-permute-start.5 = (u8[2,65536]{1,0:T(4,128)(4,1)S(1)}, u8[2,65536]{1,0:T(4,128)(4,1)S(1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%fusion.286), channel_id=1, source_target_pairs={{3,0}}
+  %collective-permute-done.5 = u8[2,65536]{1,0:T(4,128)(4,1)S(1)} collective-permute-done(%collective-permute-start.5)
+}
+"""
+
+
+def test_parse_permutes_counts_tpu_async_start_once():
+    from repro.launch.hlo_analysis import cross_pod_permute_bytes, parse_permutes
+
+    instrs = parse_permutes(_HLO_TPU_ASYNC)
+    assert [(i.nbytes, i.pairs) for i in instrs] == [(2 * 65536, ((3, 0),))]
+    assert cross_pod_permute_bytes(_HLO_TPU_ASYNC, w=1) == 2 * 65536
+
+
 # ---------------------------------- new AST rule: uninstrumented entrypoint
 
 
