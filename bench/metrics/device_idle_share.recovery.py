@@ -1,0 +1,10 @@
+"""device_idle_share.recovery: share of the traced window in which no op
+ran on the target chip, in a recovery cell; in percent."""
+from yardstick import trace
+
+
+def read(run):
+    if run.kind != "node_recovery" or run.trace is None or not trace.has_ops(
+            run.trace, run.target_device):
+        return None
+    return trace.idle_share(run.trace, run.target_device) * 100
