@@ -30,12 +30,19 @@ stripe on (1, 1).  The lowering is two-phase:
   - **decode** — the collector (output row ``target_pod * w``) gathers
     its canonical unit order and applies the decode matrix.
 
+  Each stage runs under a ``jax.named_scope`` named from
+  ``obs.STAGE_NAMES`` (``node_encode``, ``inner``, ``relayer_encode``,
+  ``cross``, ``decode``, ``write``), so the compiled HLO's ``op_name``
+  of every op names the Table-3 stage it belongs to, and a device trace
+  of the program reads per stage.  Scopes change only that metadata.
+
 :func:`spmd_repair` runs one stripe; :func:`spmd_node_recovery` runs S
 stripes in a single program with the relayer role rotating per stripe
 (paper §5.2 load balancing).  Both self-instrument through
-``repro.obs`` with the same stage names / byte counters as
-``core/repair.py``, so traced SPMD runs cross-check against the plan's
-symbolic accounting.
+``repro.obs``: a ``repair.plan`` span around the host's plan and spec
+rebuild and a ``repair.launch`` span around the program's dispatch,
+and the byte counters of ``core/repair.py``, so traced SPMD runs
+cross-check against the plan's symbolic accounting.
 """
 from __future__ import annotations
 
@@ -259,6 +266,16 @@ def mesh_layout(spec: SpmdRepairSpec, pods: int, nodes: int) -> tuple[int, int]:
     return spec.r // pods, spec.w // nodes
 
 
+def stage_scope(stage: str) -> Any:
+    """``jax.named_scope`` for one Table-3 stage of ``obs.STAGE_NAMES``:
+    the stage then heads its ops' ``op_name`` in the compiled HLO."""
+    import jax
+
+    if stage not in obs.STAGE_NAMES:
+        raise ValueError(f"{stage!r} is not one of {obs.STAGE_NAMES}")
+    return jax.named_scope(stage)
+
+
 def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
     """Build the shard_map body over a ``("pod", "node")`` mesh.
 
@@ -314,9 +331,10 @@ def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
         def rack_pool(units: list[Any]) -> Any:
             # a rack's units, node-major: local rows or all_gather over
             # the node axis only, so aggregation never crosses a pod
-            if per_rack == w:
-                return jnp.concatenate(units, axis=0)
-            return jax.lax.all_gather(units[0], "node", tiled=True)
+            with stage_scope("inner"):
+                if per_rack == w:
+                    return jnp.concatenate(units, axis=0)
+                return jax.lax.all_gather(units[0], "node", tiled=True)
 
         # inner: NodeEncode, then RelayerEncode over [own ++ rack pool];
         # relayer units are pooled in-rack too (rows w*nu .. w*nu + w*ru)
@@ -324,39 +342,47 @@ def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
                 for b in range(per_rack)] for a in range(racks)]
         pools = []
         for a in range(racks):
-            pool = rack_pool([encode(spec.node_mats, i, own(a, b))
-                              for b, i in enumerate(ids[a])])
-            if ru:
-                zs = [encode(spec.relayer_mats, i,
-                             jnp.concatenate([own(a, b), pool], axis=0))
+            with stage_scope("node_encode"):
+                ys = [encode(spec.node_mats, i, own(a, b))
                       for b, i in enumerate(ids[a])]
-                pool = jnp.concatenate([pool, rack_pool(zs)], axis=0)
+            pool = rack_pool(ys)
+            if ru:
+                with stage_scope("relayer_encode"):
+                    zs = [encode(spec.relayer_mats, i,
+                                 jnp.concatenate([own(a, b), pool], axis=0))
+                          for b, i in enumerate(ids[a])]
+                relayed = rack_pool(zs)
+                with stage_scope("inner"):
+                    pool = jnp.concatenate([pool, relayed], axis=0)
             pools.append(pool)
 
         # cross: each source rack ships exactly its scheduled units to
         # the target rack — one collective-permute per source pod when
         # racks are devices, so compiled cross-pod bytes ==
         # sum(len(rows)) * sub; a local take when they share one
-        if racks == 1:
-            pool = pools[0]
-            recvs = [
-                jax.lax.ppermute(take(pool, rows), "pod",
-                                 [(q, spec.target_pod)])
-                for q, rows in cross
-            ]
-        else:
-            pool = pools[spec.target_pod]
-            recvs = [take(pools[q], rows) for q, rows in cross]
-        pool2 = jnp.concatenate([pool, *recvs], axis=0) if recvs else pool
+        with stage_scope("cross"):
+            if racks == 1:
+                pool = pools[0]
+                recvs = [
+                    jax.lax.ppermute(take(pool, rows), "pod",
+                                     [(q, spec.target_pod)])
+                    for q, rows in cross
+                ]
+            else:
+                pool = pools[spec.target_pod]
+                recvs = [take(pools[q], rows) for q, rows in cross]
+            pool2 = jnp.concatenate([pool, *recvs], axis=0) if recvs else pool
 
         # decode; only the collector's row keeps it
-        rec = gf_matmul_jnp(decode, take(pool2, spec.target_idx))
-        zero = jnp.zeros_like(rec)
-        return jnp.stack([
-            (rec if i == collector else zero) if isinstance(i, int)
-            else jnp.where(i == collector, rec, zero)
-            for row in ids for i in row
-        ])
+        with stage_scope("decode"):
+            rec = gf_matmul_jnp(decode, take(pool2, spec.target_idx))
+        with stage_scope("write"):
+            zero = jnp.zeros_like(rec)
+            return jnp.stack([
+                (rec if i == collector else zero) if isinstance(i, int)
+                else jnp.where(i == collector, rec, zero)
+                for row in ids for i in row
+            ])
 
     return repair
 
@@ -397,28 +423,22 @@ def spmd_repair(
     import jax
     from jax.sharding import PartitionSpec as P
 
-    plan = code.repair_plan(failed)
-    spec = plan_to_spmd(code, plan)
-    _check_mesh(spec, mesh)
-    sub_bytes = int(payloads.shape[-1])
-    fn = jax.shard_map(
-        make_spmd_repair(spec), mesh=mesh,
-        in_specs=P(("pod", "node")), out_specs=P(("pod", "node")),
-    )
-    jit_fn = jax.jit(fn, donate_argnums=0 if donate else ())
-    # the three stages execute fused inside one XLA program, so the
-    # stage spans carry the static schedule (unit counts) and the
-    # counters carry the bytes; wall time lives on the decode span,
-    # which encloses the actual dispatch
-    with obs.span("repair.spmd", cat="repair", failed=failed,
-                  family=spec.family, alpha=spec.alpha, sub_bytes=sub_bytes):
-        with obs.span("repair.inner", cat="repair", units=spec.inner_units):
-            _record_schedule(spec, sub_bytes)
-        with obs.span("repair.cross", cat="repair", units=spec.cross_units,
-                      permutes=len([r for r in spec.cross_idx if r])):
-            pass
-        with obs.span("repair.decode", cat="repair",
-                      units=len(spec.target_idx)):
+    with obs.span("repair.spmd", cat="repair", failed=failed) as root:
+        with obs.span("repair.plan", cat="repair"):
+            plan = code.repair_plan(failed)
+            spec = plan_to_spmd(code, plan)
+            _check_mesh(spec, mesh)
+            fn = jax.shard_map(
+                make_spmd_repair(spec), mesh=mesh,
+                in_specs=P(("pod", "node")), out_specs=P(("pod", "node")),
+            )
+            jit_fn = jax.jit(fn, donate_argnums=0 if donate else ())
+        sub_bytes = int(payloads.shape[-1])
+        root.set_attr("family", spec.family)
+        root.set_attr("alpha", spec.alpha)
+        root.set_attr("sub_bytes", sub_bytes)
+        _record_schedule(spec, sub_bytes)
+        with obs.span("repair.launch", cat="repair"):
             out = jit_fn(payloads)
     return out, spec
 
@@ -451,8 +471,15 @@ def node_recovery_program(
         bodies = [make_spmd_repair(spec) for spec in specs]
 
         def body(x: Any) -> Any:  # (S, racks*nodes, alpha, sub) per device
-            return jnp.stack([fn(x[s]) for s, fn in enumerate(bodies)],
-                             axis=0)
+            # a stripe's rows are NodeEncode's input: its split from the
+            # stack copies the blocks on the TPU, so it is scoped with it
+            outs = []
+            for s, fn in enumerate(bodies):
+                with stage_scope("node_encode"):
+                    stripe = x[s]
+                outs.append(fn(stripe))
+            with stage_scope("write"):
+                return jnp.stack(outs, axis=0)
 
         prog = jax.jit(jax.shard_map(
             body, mesh=mesh,
@@ -474,16 +501,18 @@ def spmd_node_recovery(
     repair load balance).  Returns ((S, n, alpha, sub), specs).
     """
     n_stripes = int(payloads.shape[0])
-    prog, specs = node_recovery_program(code, failed, n_stripes, mesh)
     sub_bytes = int(payloads.shape[-1])
     with obs.span("repair.spmd_node_recovery", cat="repair", failed=failed,
-                  family=specs[0].family if specs else "", stripes=n_stripes,
-                  distinct_relayer_sets=len(
-                      {tuple(sp.rel_idx.tolist()) for sp in specs}
-                  )):
+                  stripes=n_stripes) as root:
+        with obs.span("repair.plan", cat="repair"):
+            prog, specs = node_recovery_program(code, failed, n_stripes, mesh)
+        root.set_attr("family", specs[0].family if specs else "")
+        root.set_attr("distinct_relayer_sets", len(
+            {tuple(sp.rel_idx.tolist()) for sp in specs}))
         for spec in specs:
             _record_schedule(spec, sub_bytes)
-        out = prog(payloads)
+        with obs.span("repair.launch", cat="repair"):
+            out = prog(payloads)
     return out, specs
 
 

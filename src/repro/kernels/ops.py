@@ -13,7 +13,6 @@ verifier and the chip smoke run call it).  It
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -61,11 +60,12 @@ def gf_matmul(
     """GF(256) coding product: (R, K) @ (K, B) -> (R, B) uint8.
 
     Under an active `repro.obs` tracer every invocation records a
-    ``kernel.gf_matmul`` span with wall-clock and achieved GB/s (payload
-    in + out bytes; the timing blocks on the result, so traced runs are
-    synchronous) and ``path`` naming what ran: ``pallas``,
-    ``pallas_interpret`` or ``ref``.  With tracing off the only extra
-    work is one global read — the dispatch path is untouched.
+    ``kernel.gf_matmul`` span around its dispatch, with ``path`` naming
+    what ran (``pallas``, ``pallas_interpret`` or ``ref``), and counts
+    its payload bytes (in + out) and calls.  Dispatch is asynchronous
+    and the span does not wait for the result: the device trace times
+    the kernel.  With tracing off the only extra work is one global
+    read.
     """
     m_np = np.asarray(m, dtype=np.uint8)
     r, k = m_np.shape
@@ -76,22 +76,16 @@ def gf_matmul(
     tracer = obs.current()
     if tracer is None:
         return _dispatch(m_np, x, r, k, b, block_b, interpret)
-    t0 = time.perf_counter()
-    y = _dispatch(m_np, x, r, k, b, block_b, interpret)
-    # traced timing must observe the finished result: sync is the point
-    jax.block_until_ready(y)  # check: ignore[host-sync]
-    dt = max(time.perf_counter() - t0, 1e-9)
     if b < _LANE:
         path = "ref"
     else:
         path = "pallas_interpret" if interpret else "pallas"
+    with tracer.span("kernel.gf_matmul", cat="kernel", r=r, k=k, b=b,
+                     path=path):
+        y = _dispatch(m_np, x, r, k, b, block_b, interpret)
     moved = (k + r) * b  # payload bytes in + out
-    tracer.record_span("kernel.gf_matmul", dt, cat="kernel", track="kernel",
-                       at_s=tracer.now_us() / 1e6 - dt,
-                       r=r, k=k, b=b, path=path, gbps=moved / dt / 1e9)
     tracer.counter_add("kernel.gf_matmul.bytes", moved, path=path)
     tracer.counter_add("kernel.gf_matmul.calls", 1, path=path)
-    tracer.gauge_set("kernel.gf_matmul.gbps", moved / dt / 1e9, path=path)
     return y
 
 

@@ -21,6 +21,16 @@ without plumbing a tracer argument through every call, and worker
 threads spawned under an active tracer record into it too.  When no
 tracer is active every module-level helper is a no-op that costs one
 global read.
+
+The module-level :func:`span` has a second sink: while a JAX profiler
+session records (``jax.profiler.trace`` / ``start_trace``), it also
+writes the span's bare name into the profiler's trace, as
+``jax.profiler.TraceAnnotation`` does, whether or not a `Tracer` is
+active.  That sink shares the clock of the device events in the same
+trace, so host spans line up with the device's ops; a `Tracer`'s spans
+and its Chrome export keep their own ``monotonic_ns`` timebase.  The
+profiler being on is the only switch.  ``jaxlib`` is imported on the
+first span, so this module imports without JAX.
 """
 from __future__ import annotations
 
@@ -28,7 +38,7 @@ import contextlib
 import itertools
 import threading
 import time
-from typing import Any, Iterator
+from typing import Any, Callable, Iterator
 
 from .metrics import MetricSet
 
@@ -214,10 +224,47 @@ def tracing(name: str = "trace") -> Iterator[Tracer]:
         yield t
 
 
+def _never() -> bool:
+    return False
+
+
+def _load_profiler_check() -> bool:
+    """Bind ``_profiling`` to the profiler's static ``TraceMe.is_enabled``
+    (jaxlib's, the base of ``jax.profiler.TraceAnnotation``) on first
+    use; without jaxlib no profiler can record."""
+    global _profiling, _TraceMe
+    try:
+        from jaxlib._profiler import TraceMe
+    except ImportError:
+        _profiling = _never
+    else:
+        _TraceMe, _profiling = TraceMe, TraceMe.is_enabled
+    return _profiling()
+
+
+_profiling: Callable[[], bool] = _load_profiler_check
+_TraceMe: Any = None
+
+
+@contextlib.contextmanager
+def _profiled_span(t: Tracer | None, name: str, cat: str,
+                   attrs: dict[str, Any]) -> Iterator[Any]:
+    # the bare name: an encoded name would rename the profiler's event
+    with _TraceMe(name):
+        if t is None:
+            yield NULL_SPAN
+        else:
+            with t.span(name, cat, **attrs) as s:
+                yield s
+
+
 def span(name: str, cat: str = "",
          **attrs: Any) -> contextlib.AbstractContextManager[Any]:
-    """Span on the active tracer; a shared no-op when tracing is off."""
+    """Span on the active tracer and, while a JAX profiler session
+    records, in its trace; a shared no-op when neither is on."""
     t = _active
+    if _profiling():
+        return _profiled_span(t, name, cat, attrs)
     if t is None:
         return NULL_SPAN
     return t.span(name, cat, **attrs)

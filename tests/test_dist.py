@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.dist.sharding import Rules, make_rules, resolve_spec
+from repro.obs import STAGE_NAMES
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -336,6 +337,37 @@ def test_spmd_repair_device_layouts(pods, n, k, r, family):
         devices=4,
     )
     assert "OK" in out
+
+
+@pytest.mark.parametrize("family, missing", [("DRC", set()),
+                                              ("RS", {"relayer_encode"})])
+def test_compiled_program_ops_carry_every_stage_scope(family, missing):
+    """One node per device, so the rack pool is an all_gather and the
+    cross stage a ppermute: the compiled HLO names every Table-3 stage
+    the code has in its ops' op_name (RS has no relayers; ``disk`` has no
+    scope, as the stripes are resident)."""
+    out = run_sub(
+        f"""
+        import re
+        import jax, jax.numpy as jnp
+        from repro import obs
+        from repro.core.codes import make_code
+        from repro.dist.collectives import node_recovery_program
+        mesh = jax.make_mesh((3, 3), ('pod', 'node'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        code = make_code('{family}', 9, 6, 3)
+        prog, _ = node_recovery_program(code, 0, 2, mesh)
+        x = jnp.zeros((2, 9, code.alpha, 256), jnp.uint8)
+        text = prog.lower(x).compile().as_text()
+        seen = set()
+        for op in re.findall(r'op_name="([^"]*)"', text):
+            seen.update([p for p in op.split('/') if p in obs.STAGE_NAMES][:1])
+        print('STAGES', sorted(seen))
+        """,
+        devices=9,
+    )
+    want = set(STAGE_NAMES) - {"disk"} - missing
+    assert f"STAGES {sorted(want)}" in out
 
 
 def test_spmd_repair_rejects_mesh_without_layout():
