@@ -10,13 +10,18 @@ the dtype discipline statically, for every registered shape:
   every grid point; ``index * block_shape`` must stay inside the full
   array for each dimension.  Pallas silently clamps or wraps
   out-of-bounds blocks depending on backend — a wrong index map
-  corrupts payloads without crashing.
-* ``lowered.pallas.out-alias`` — the output index map must be injective
-  across the grid: two grid steps writing the same output block is a
-  write-write race whose winner depends on grid iteration order.
+  corrupts payloads without crashing.  Along the geometry's
+  ``clipped_dim`` (a ragged grid) a block may run past the array's end,
+  where Pallas clips it, but must still start inside the array: a block
+  that starts at or past the end holds no element of it.
+* ``lowered.pallas.out-alias`` — the output blocks of the grid's steps,
+  as element ranges clipped at the array's end, must be pairwise
+  disjoint: two grid steps writing the same element is a write-write
+  race whose winner depends on grid iteration order.
 * ``lowered.pallas.gf-dtype`` — an AST pass over the kernel sources.
   GF(2^8) code lives in uint8; ``+``/``-``/``*`` on uint8 wraps mod 256
-  silently (GF addition is XOR, not ``+``), reductions widen to the
+  silently (GF addition is XOR, not ``+``), so does a negation (the
+  kernel's all-ones masks need 32 bits), reductions widen to the
   input dtype unless told otherwise, and an MXU matmul without
   ``preferred_element_type`` accumulates in the input dtype — for int8
   bitplanes that overflows at K >= 16.  The pass tracks uint8-ness
@@ -50,6 +55,12 @@ R_PL_DTYPE = "lowered.pallas.gf-dtype"
 
 def _grid_points(grid: Sequence[int]) -> Iterable[tuple[int, ...]]:
     return itertools.product(*(range(g) for g in grid))
+
+
+def _extent(geom: Any, d: int, start: int, blk: int, dim: int) -> int:
+    """End of a block's elements along dim d: clipped at the array's
+    end along the geometry's ``clipped_dim``, else the block's own."""
+    return min(start + blk, dim) if d == geom.clipped_dim else start + blk
 
 
 def _check_operand(
@@ -89,11 +100,12 @@ def _check_operand(
             return out
         for d, (i, blk, dim) in enumerate(zip(idx, block, shape)):
             start = i * blk
-            if i < 0 or start + blk > dim:
+            stop = _extent(geom, d, start, blk, dim)
+            if i < 0 or start >= dim or stop > dim:
                 out.append(Finding(
                     R_PL_OOB, FAIL,
                     f"{geom.name}/{what}: grid point {point} maps dim {d} "
-                    f"to elements [{start}, {start + blk}) outside "
+                    f"to elements [{start}, {stop}) outside "
                     f"[0, {dim}) — Pallas would clamp or wrap this block "
                     f"silently",
                     {"point": list(point), "dim": d, "start": start,
@@ -129,7 +141,14 @@ def check_pallas_oob(geom: Any) -> list[Finding]:
 
 @rule(R_PL_ALIAS, PALLAS_FAMILY)
 def check_pallas_out_alias(geom: Any) -> list[Finding]:
-    """The output index map is injective across the grid."""
+    """The grid's output blocks, clipped at the array's end, are
+    pairwise disjoint.
+
+    Every output block has one shape and starts on a multiple of it, so
+    two blocks meet exactly where their indices are equal: a clipped
+    block is a part of its whole block, and whole blocks with different
+    indices do not meet.  A block that starts past the array's end holds
+    no element: that is the oob rule's finding."""
     out: list[Finding] = []
     seen: dict[tuple[int, ...], tuple[int, ...]] = {}
     for point in _grid_points(geom.grid):
@@ -137,14 +156,19 @@ def check_pallas_out_alias(geom: Any) -> list[Finding]:
             idx = tuple(int(v) for v in geom.out_index_map(*point))
         except Exception:
             return out  # crash is the oob rule's finding, not an alias
+        box = [(i * blk, _extent(geom, d, i * blk, blk, dim))
+               for d, (i, blk, dim) in enumerate(
+                   zip(idx, geom.out_block, geom.out_shape))]
+        if any(a >= b for a, b in box):
+            continue
         if idx in seen:
             out.append(Finding(
                 R_PL_ALIAS, FAIL,
                 f"{geom.name}: grid points {seen[idx]} and {point} both "
-                f"write output block {idx} — a write-write race whose "
-                f"winner depends on grid iteration order",
-                {"block": list(idx), "first": list(seen[idx]),
-                 "second": list(point)},
+                f"write output block {idx}, elements {box} — a write-write "
+                f"race whose winner depends on grid iteration order",
+                {"block": list(idx), "elements": [list(r) for r in box],
+                 "first": list(seen[idx]), "second": list(point)},
             ))
             return out
         seen[idx] = point
@@ -227,6 +251,16 @@ def _scan_expr(
     findings: list[Finding],
 ) -> None:
     for sub in ast.walk(node):
+        if (isinstance(sub, ast.UnaryOp) and isinstance(sub.op, ast.USub)
+                and env.is_u8(sub.operand)):
+            findings.append(Finding(
+                R_PL_DTYPE, FAIL,
+                f"{path}:{sub.lineno} ({fn_name}): negation of a uint8 "
+                f"operand wraps mod 256 silently — -1 is 0xFF, not all "
+                f"ones; widen first",
+                {"path": path, "line": sub.lineno, "fn": fn_name,
+                 "op": "USub"},
+            ))
         if isinstance(sub, ast.BinOp):
             if isinstance(sub.op, _WRAP_OPS) and (
                 env.is_u8(sub.left) or env.is_u8(sub.right)
@@ -334,12 +368,23 @@ def check_gf_dtype(path: str, source: str) -> list[Finding]:
 
 # (r, k, b, block_b) shapes swept by default — bracketing the coding
 # shapes the paper's configurations actually hit (ops.choose_block_b
-# picks block_b <= 4096, lane-aligned).
+# picks a multiple of 512 bytes).
 GEOMETRY_SHAPES: tuple[tuple[int, int, int, int], ...] = (
-    (2, 4, 1024, 256),
+    (2, 4, 1024, 512),
     (3, 6, 4096, 512),
     (4, 8, 2048, 512),
     (3, 9, 65536, 4096),
+    # ragged grids: the last block clipped at the payload's end
+    (3, 6, 4096 + 384, 512),
+    (2, 3, 200, 512),
+    # each cell's GF products at their real widths (128 x a prime for
+    # DRC(9,6,3) and its 1 MiB strips), tiled as ops.choose_block_b does
+    (3, 3, 22369664, 65536),
+    (3, 12, 22369664, 32768),
+    (3, 12, 349568, 32768),
+    (1, 1, 67108864, 65536),
+    (1, 6, 67108864, 65536),
+    (2, 8, 33554432, 32768),
 )
 
 _KERNEL_MODULES = ("repro.kernels.gf_matmul", "repro.kernels.ops")
@@ -397,8 +442,9 @@ def verify_kernel_source(
 PALLAS_MUTATIONS: dict[str, str] = {
     "pallas_oob_index_map": R_PL_OOB,
     "pallas_alias_out": R_PL_ALIAS,
-    "pallas_sum_no_dtype": R_PL_DTYPE,
-    "pallas_acc_wrap": R_PL_DTYPE,
+    "pallas_clip_past_end": R_PL_OOB,
+    "pallas_clip_overlap": R_PL_ALIAS,
+    "pallas_mask_wrap": R_PL_DTYPE,
 }
 
 
@@ -421,17 +467,30 @@ def mutate_pallas(
         return "geometry", dataclasses.replace(
             geom, out_index_map=lambda j: (0, 0)
         )
-    if mutation == "pallas_sum_no_dtype":
-        # drop the explicit accumulator dtype of the pack-bits reduction
-        needle = "axis=1, dtype=jnp.int32"
+    last = geom.grid[0] - 1
+    if mutation == "pallas_clip_past_end":
+        # the clipped last block of payload and output shifted one block
+        # on: it starts at or past the array's end
+        def shift(j: int) -> tuple[int, int]:
+            return (0, j + 1 if j == last else j)
+
+        return "geometry", dataclasses.replace(
+            geom, in_index_maps=(geom.in_index_maps[0], shift),
+            out_index_map=shift,
+        )
+    if mutation == "pallas_clip_overlap":
+        # the clipped last block written over the block before it
+        return "geometry", dataclasses.replace(
+            geom, out_index_map=lambda j: (0, min(j, last - 1)),
+        )
+    if mutation == "pallas_mask_wrap":
+        # the coefficient bits negated without their widening to int32:
+        # a uint8 negation wraps mod 256, and a mask must be all ones in
+        # 32 bits to select all four bytes of a word
+        needle = "m.astype(np.int32)[..., None]"
         if needle not in source:
             raise ValueError(f"mutation target {needle!r} not in source")
-        return "source", source.replace(needle, "axis=1", 1)
-    if mutation == "pallas_acc_wrap":
-        needle = "preferred_element_type=jnp.int32,"
-        if needle not in source:
-            raise ValueError(f"mutation target {needle!r} not in source")
-        return "source", source.replace(needle, "", 1)
+        return "source", source.replace(needle, "m[..., None]", 1)
     raise ValueError(f"unknown pallas mutation {mutation!r}")
 
 

@@ -231,7 +231,7 @@ def capture_spmd_repair(
     import jax.numpy as jnp
 
     from repro.core.codes import make_code
-    from repro.dist.collectives import make_spmd_repair, plan_to_spmd
+    from repro.dist.collectives import gf_path, make_spmd_repair, plan_to_spmd
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     code = make_code(family, n, k, r=r)
@@ -240,7 +240,7 @@ def capture_spmd_repair(
     require_devices(spec.r * spec.w)
     mesh = jax.make_mesh((spec.r, spec.w), ("pod", "node"))
     fn = jax.shard_map(
-        make_spmd_repair(spec), mesh=mesh,
+        make_spmd_repair(spec, gf_path(mesh)), mesh=mesh,
         in_specs=P(("pod", "node")), out_specs=P(("pod", "node")),
     )
     # the input carries the mesh sharding, so the donated buffer can
@@ -282,24 +282,20 @@ def capture_gf_ref(rows: int = 3, k: int = 6, sub: int = 256) -> TracedProgram:
 def capture_gf_pallas(
     rows: int = 3, k: int = 6, sub: int = 1024, block_b: int = 512
 ) -> TracedProgram:
-    """The Pallas bitplane kernel call site (kernel jaxpr included)."""
+    """The Pallas kernel call site (kernel jaxpr included)."""
     import jax
     import jax.numpy as jnp
 
     from repro.kernels.gf_matmul import gf_matmul_pallas
-    from repro.kernels.ops import bit_expand
 
-    mb_np = bit_expand(
-        np.arange(rows * k, dtype=np.uint8).reshape(rows, k)
-    )
-    mb = jax.ShapeDtypeStruct(mb_np.shape, jnp.int8)
+    masks = jax.ShapeDtypeStruct((rows, 8 * k), jnp.int32)
     x = jax.ShapeDtypeStruct((k, sub), jnp.uint8)
 
-    def call(mb: Any, x: Any) -> Any:
-        return gf_matmul_pallas(mb, x, block_b=block_b, interpret=True)
+    def call(masks: Any, x: Any) -> Any:
+        return gf_matmul_pallas(masks, x, block_b=block_b, interpret=True)
 
     return _capture(
-        f"gf_matmul_pallas[{rows}x{k}x{sub}]", KERNEL, call, (mb, x),
+        f"gf_matmul_pallas[{rows}x{k}x{sub}]", KERNEL, call, (masks, x),
         payload_invars=(1,), payload_outvars=(0,),
     )
 

@@ -14,10 +14,11 @@ The lattice: a value is **tainted** when it (transitively) derives from
 GF payload bytes *while still uint8*.  Sources are the program's
 declared payload inputs and every uint8 constant (the GF mul/log
 tables).  Taint propagates through bitwise and structural ops; it is
-*cleared* by a conversion out of uint8 — the two sanctioned exits:
-int32/int64 for table-gather indices and int8 for the bitplane kernel's
-GF(2) planes (both leave the byte domain deliberately, and re-entering
-it from clean values is plain data movement).  Violations:
+*cleared* by a conversion out of uint8 to another integer dtype — the
+sanctioned exit, int32/int64 for table-gather indices, leaves the byte
+domain deliberately, and re-entering it from clean values is plain data
+movement.  A bitcast keeps the taint: the Pallas kernel's 32-bit words
+are payload bytes four at a time.  Violations:
 
 * ``wrap-arith`` — an integer-ring op (add/sub/mul/dot/reduce_sum/...)
   consumes a tainted operand: GF addition is XOR, so this wraps.
@@ -170,7 +171,7 @@ class _TaintInterp:
             elif src_taint and dst == "uint8":
                 self._set_outs(env, eqn, True)
             else:
-                # leaving uint8 is a sanctioned exit (indices / bitplanes)
+                # leaving uint8 is the sanctioned exit (indices)
                 self._set_outs(env, eqn, False)
         elif prim == "select_n":
             self._set_outs(env, eqn, any(in_t[1:]))  # predicate carries none

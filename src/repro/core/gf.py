@@ -203,35 +203,6 @@ def rs_generator(n: int, k: int) -> np.ndarray:
     return np.concatenate([np.eye(k, dtype=np.uint8), parity], axis=0)
 
 
-def gf_mul_bitmatrix(c: int) -> np.ndarray:
-    """8x8 GF(2) matrix M with: bits(c * x) = M @ bits(x) (mod 2).
-
-    Column j is bits(c * 2^j).  Bit order: LSB first.
-    """
-    m = np.zeros((8, 8), dtype=np.uint8)
-    for j in range(8):
-        prod = int(gf_mul(c, 1 << j))
-        for i in range(8):
-            m[i, j] = (prod >> i) & 1
-    return m
-
-
-def gf_matrix_to_bitmatrix(a: np.ndarray) -> np.ndarray:
-    """Expand (m,k) GF(256) matrix to (8m,8k) GF(2) bit-matrix.
-
-    This is the TPU-native representation: GF(256) matmul == bit-matrix
-    matmul over GF(2) on bit-unpacked data (see kernels/gf_matmul.py).
-    """
-    a = np.asarray(a, dtype=np.uint8)
-    m, k = a.shape
-    out = np.zeros((8 * m, 8 * k), dtype=np.uint8)
-    for i in range(m):
-        for j in range(k):
-            if a[i, j]:
-                out[8 * i : 8 * i + 8, 8 * j : 8 * j + 8] = gf_mul_bitmatrix(int(a[i, j]))
-    return out
-
-
 class GFRandom:
     """Deterministic GF(256) randomness for construction searches."""
 

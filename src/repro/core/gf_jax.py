@@ -5,8 +5,10 @@ executes the resulting matrices against real payload bytes as jitted JAX ops.
 Two interchangeable execution paths:
 
 * ``gf_matmul_jnp`` — pure-jnp shift-and-add product on uint8 (runs
-  everywhere; the product of the SPMD repair and checkpoint encode paths).
-* ``repro.kernels.ops.gf_matmul`` — Pallas TPU kernel (bitplane MXU matmul);
+  everywhere; the SPMD repair program's product off a TPU, the
+  checkpoint encode's, and the kernel's reference).
+* ``repro.kernels.ops.gf_matmul`` — Pallas TPU kernel (shift-and-add on
+  four bytes a 32-bit word), the repair program's product on a TPU;
   validated against this module in interpret mode.
 """
 from __future__ import annotations

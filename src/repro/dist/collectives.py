@@ -276,7 +276,46 @@ def stage_scope(stage: str) -> Any:
     return jax.named_scope(stage)
 
 
-def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
+def gf_path(mesh: Any) -> str:
+    """The GF(2^8) product the repair program takes on ``mesh``:
+    ``pallas``, the Pallas kernel over lane-dense 32-bit words, where the
+    mesh's devices are TPUs; ``jnp``, ``gf_matmul_jnp``, anywhere else."""
+    return "pallas" if mesh.devices.flat[0].platform == "tpu" else "jnp"
+
+
+def _node_ids(spec: SpmdRepairSpec, pods: int, nodes: int
+              ) -> list[list[list[int]]]:
+    """For local rack a and its node b, the node ids that slot holds on
+    the devices of a ``(pods, nodes)`` mesh, one per device in the order
+    of its mesh position ``p * nodes + j``: one id where one device
+    holds every rack."""
+    racks, per_rack = mesh_layout(spec, pods, nodes)
+    return [[[(p * racks + a) * spec.w + j * per_rack + b
+              for p in range(pods) for j in range(nodes)]
+             for b in range(per_rack)] for a in range(racks)]
+
+
+def _runs_product(mats: np.ndarray, held: list[int]) -> bool:
+    # a slot whose nodes have nothing to send, on every device, computes
+    # nothing
+    return bool(mats[held].any())
+
+
+def gf_products(spec: SpmdRepairSpec, pods: int, nodes: int) -> dict[str, int]:
+    """GF products one device's repair program runs for the stripe, by
+    stage, on a ``(pods, nodes)`` mesh: what :func:`make_spmd_repair`
+    emits, as it emits them."""
+    slots = [held for rack in _node_ids(spec, pods, nodes) for held in rack]
+    out = {"node_encode": sum(_runs_product(spec.node_mats, held)
+                              for held in slots)}
+    if spec.ru:
+        out["relayer_encode"] = sum(_runs_product(spec.relayer_mats, held)
+                                    for held in slots)
+    out["decode"] = 1
+    return out
+
+
+def make_spmd_repair(spec: SpmdRepairSpec, path: str) -> Callable[[Any], Any]:
     """Build the shard_map body over a ``("pod", "node")`` mesh.
 
     Each device holds ``(racks * nodes, alpha, sub)`` of the node-major
@@ -286,17 +325,38 @@ def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
     the cross stage is a local ``take`` of the same pool rows the
     ``ppermute`` over ``pod`` would ship.  Output row ``target_pod * w``
     carries the reconstructed payload; every other row is zero.
+
+    ``path`` (:func:`gf_path` of the mesh) names the GF product: on
+    ``pallas`` each coefficient matrix is bit-expanded here, on the
+    host, into the kernel's masks and handed to it as data, so one
+    compiled kernel serves every node of a shape; on ``jnp`` the
+    matrices go to ``gf_matmul_jnp`` as they are.
     """
     import jax
     import jax.numpy as jnp
 
-    from repro.core.gf_jax import gf_matmul_jnp
+    product: Callable[[Any, Any], Any]
+    if path == "pallas":
+        from repro.kernels import ops
+
+        product = ops.gf_product
+
+        def coeffs(m: np.ndarray) -> Any:
+            return jnp.asarray(ops.bit_expand(m))
+    elif path == "jnp":
+        from repro.core.gf_jax import gf_matmul_jnp
+
+        product = gf_matmul_jnp
+
+        def coeffs(m: np.ndarray) -> Any:
+            return jnp.asarray(m)
+    else:
+        raise ValueError(f"GF path {path!r} is neither 'pallas' nor 'jnp'")
 
     w, nu, ru = spec.w, spec.nu, spec.ru
     # declared schedule; plan_to_spmd never emits a (q, q) self-send and
     # the lowered verifier rule lowered.spmd.permute-partial proves it
     cross = [(q, rows) for q, dst, rows in spec.permute_steps() if q != dst]
-    decode = jnp.asarray(spec.decode)
     collector = spec.target_pod * w
 
     def take(pool: Any, rows: tuple[int, ...]) -> Any:
@@ -304,17 +364,18 @@ def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
         # split into thousands of pieces by the TPU compiler
         return jnp.concatenate([pool[i:i + 1] for i in rows], axis=0)
 
-    def encode(mats: np.ndarray, node: Any, operand: Any) -> Any:
-        # a node id is a Python int where every device holds its rack;
-        # such a node with nothing to send then computes nothing
-        if not isinstance(node, int):
-            m = jax.lax.dynamic_index_in_dim(jnp.asarray(mats), node, 0,
-                                             keepdims=False)
-            return gf_matmul_jnp(m, operand)
-        if not mats[node].any():
+    def encode(mats: np.ndarray, held: list[int], pos: Any,
+               operand: Any) -> Any:
+        # the slot ``held`` these nodes, one a device; this device, at
+        # mesh position pos, encodes as the node it holds
+        if not _runs_product(mats, held):
             return jnp.zeros_like(operand, shape=(mats.shape[1],
                                                   operand.shape[1]))
-        return gf_matmul_jnp(jnp.asarray(mats[node]), operand)
+        if len(held) == 1:
+            return product(coeffs(mats[held[0]]), operand)
+        m = jax.lax.dynamic_index_in_dim(coeffs(mats[held]), pos, 0,
+                                         keepdims=False)
+        return product(m, operand)
 
     def repair(x: Any) -> Any:
         pods = jax.lax.axis_size("pod")
@@ -338,19 +399,23 @@ def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
 
         # inner: NodeEncode, then RelayerEncode over [own ++ rack pool];
         # relayer units are pooled in-rack too (rows w*nu .. w*nu + w*ru)
-        ids = [[(p * racks + a) * w + j * per_rack + b
-                for b in range(per_rack)] for a in range(racks)]
+        pos = p * nodes + j  # this device's mesh position
+        held = _node_ids(spec, pods, nodes)
+        # each slot's node on this device: a Python int where one device
+        # holds every rack
+        ids = [[h[0] if len(h) == 1 else jnp.asarray(h)[pos] for h in rack]
+               for rack in held]
         pools = []
         for a in range(racks):
             with stage_scope("node_encode"):
-                ys = [encode(spec.node_mats, i, own(a, b))
-                      for b, i in enumerate(ids[a])]
+                ys = [encode(spec.node_mats, held[a][b], pos, own(a, b))
+                      for b in range(per_rack)]
             pool = rack_pool(ys)
             if ru:
                 with stage_scope("relayer_encode"):
-                    zs = [encode(spec.relayer_mats, i,
+                    zs = [encode(spec.relayer_mats, held[a][b], pos,
                                  jnp.concatenate([own(a, b), pool], axis=0))
-                          for b, i in enumerate(ids[a])]
+                          for b in range(per_rack)]
                 relayed = rack_pool(zs)
                 with stage_scope("inner"):
                     pool = jnp.concatenate([pool, relayed], axis=0)
@@ -375,7 +440,7 @@ def make_spmd_repair(spec: SpmdRepairSpec) -> Callable[[Any], Any]:
 
         # decode; only the collector's row keeps it
         with stage_scope("decode"):
-            rec = gf_matmul_jnp(decode, take(pool2, spec.target_idx))
+            rec = product(coeffs(spec.decode), take(pool2, spec.target_idx))
         with stage_scope("write"):
             zero = jnp.zeros_like(rec)
             return jnp.stack([
@@ -408,6 +473,14 @@ def _record_schedule(spec: SpmdRepairSpec, sub_bytes: int) -> None:
             obs.counter_add("repair.units_cross", len(rows), pod=str(q))
 
 
+def _record_gf(path: str, products: dict[str, int]) -> None:
+    """Book the call's GF products that run as the Pallas kernel, by
+    stage (none on the ``jnp`` path)."""
+    for stage, count in products.items():
+        obs.counter_add("repair.gf_kernel_calls",
+                        count if path == "pallas" else 0, stage=stage)
+
+
 def spmd_repair(
     code: ErasureCode, failed: int, payloads: Any, mesh: Any,
     *, donate: bool = False
@@ -428,8 +501,9 @@ def spmd_repair(
             plan = code.repair_plan(failed)
             spec = plan_to_spmd(code, plan)
             _check_mesh(spec, mesh)
+            path = gf_path(mesh)
             fn = jax.shard_map(
-                make_spmd_repair(spec), mesh=mesh,
+                make_spmd_repair(spec, path), mesh=mesh,
                 in_specs=P(("pod", "node")), out_specs=P(("pod", "node")),
             )
             jit_fn = jax.jit(fn, donate_argnums=0 if donate else ())
@@ -437,26 +511,23 @@ def spmd_repair(
         root.set_attr("family", spec.family)
         root.set_attr("alpha", spec.alpha)
         root.set_attr("sub_bytes", sub_bytes)
+        root.set_attr("gf_path", path)
         _record_schedule(spec, sub_bytes)
+        _record_gf(path, gf_products(spec, *mesh.devices.shape))
         with obs.span("repair.launch", cat="repair"):
             out = jit_fn(payloads)
     return out, spec
 
 
-# One jitted program per (code, failed node, stripe count, mesh).
-_RECOVERY_PROGRAMS: dict[tuple[str, int, int, Any], Any] = {}
+# One jitted program per (code, failed node, stripe count, mesh), with
+# its GF path and the GF products one device runs a call, by stage.
+_RECOVERY_PROGRAMS: dict[tuple[str, int, int, Any],
+                         tuple[Any, str, dict[str, int]]] = {}
 
 
-def node_recovery_program(
+def _recovery_program(
     code: ErasureCode, failed: int, n_stripes: int, mesh: Any
-) -> tuple[Any, list[SpmdRepairSpec]]:
-    """The jitted ``(S, n, alpha, sub) -> (S, n, alpha, sub)`` program
-    :func:`spmd_node_recovery` runs, plus its per-stripe specs.
-
-    Stripe s uses ``repair_plan(failed, rotation=s)``.  Built once per
-    (code, failed, S, mesh); ``.lower(...)`` on the result gives the
-    compiled module for byte and memory checks.
-    """
+) -> tuple[Any, list[SpmdRepairSpec], str, dict[str, int]]:
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -466,9 +537,14 @@ def node_recovery_program(
     for spec in specs:
         _check_mesh(spec, mesh)
     key = (repr(code), failed, n_stripes, mesh)
-    prog = _RECOVERY_PROGRAMS.get(key)
-    if prog is None:
-        bodies = [make_spmd_repair(spec) for spec in specs]
+    built = _RECOVERY_PROGRAMS.get(key)
+    if built is None:
+        path = gf_path(mesh)
+        bodies = [make_spmd_repair(spec, path) for spec in specs]
+        products: dict[str, int] = {}
+        for spec in specs:
+            for stage, count in gf_products(spec, *mesh.devices.shape).items():
+                products[stage] = products.get(stage, 0) + count
 
         def body(x: Any) -> Any:  # (S, racks*nodes, alpha, sub) per device
             # a stripe's rows are NodeEncode's input: its split from the
@@ -486,7 +562,23 @@ def node_recovery_program(
             in_specs=P(None, ("pod", "node")),
             out_specs=P(None, ("pod", "node")),
         ))
-        _RECOVERY_PROGRAMS[key] = prog
+        built = _RECOVERY_PROGRAMS[key] = (prog, path, products)
+    prog, path, products = built
+    return prog, specs, path, products
+
+
+def node_recovery_program(
+    code: ErasureCode, failed: int, n_stripes: int, mesh: Any
+) -> tuple[Any, list[SpmdRepairSpec]]:
+    """The jitted ``(S, n, alpha, sub) -> (S, n, alpha, sub)`` program
+    :func:`spmd_node_recovery` runs, plus its per-stripe specs.
+
+    Stripe s uses ``repair_plan(failed, rotation=s)``.  Built once per
+    (code, failed, S, mesh), on the GF path :func:`gf_path` picks for
+    the mesh; ``.lower(...)`` on the result gives the compiled module
+    for byte and memory checks.
+    """
+    prog, specs, _, _ = _recovery_program(code, failed, n_stripes, mesh)
     return prog, specs
 
 
@@ -499,18 +591,25 @@ def spmd_node_recovery(
     ``repair_plan(failed, rotation=s)`` so the relayer role rotates
     across the helper nodes of each remote rack (paper §5.2: node-level
     repair load balance).  Returns ((S, n, alpha, sub), specs).
+
+    The root span carries ``gf_path`` (:func:`gf_path`), and the
+    counter ``repair.gf_kernel_calls`` counts, by stage, the GF products
+    of the call that run as the Pallas kernel.
     """
     n_stripes = int(payloads.shape[0])
     sub_bytes = int(payloads.shape[-1])
     with obs.span("repair.spmd_node_recovery", cat="repair", failed=failed,
                   stripes=n_stripes) as root:
         with obs.span("repair.plan", cat="repair"):
-            prog, specs = node_recovery_program(code, failed, n_stripes, mesh)
+            prog, specs, path, products = _recovery_program(
+                code, failed, n_stripes, mesh)
         root.set_attr("family", specs[0].family if specs else "")
         root.set_attr("distinct_relayer_sets", len(
             {tuple(sp.rel_idx.tolist()) for sp in specs}))
+        root.set_attr("gf_path", path)
         for spec in specs:
             _record_schedule(spec, sub_bytes)
+        _record_gf(path, products)
         with obs.span("repair.launch", cat="repair"):
             out = prog(payloads)
     return out, specs

@@ -3,51 +3,52 @@
 ``gf_matmul(m, x)`` is the kernel's entry point (benchmarks, tests, the
 verifier and the chip smoke run call it).  It
 
-* bit-expands the GF(256) coding matrix host-side (cached by content),
-* pads the payload byte axis to the chosen lane-aligned tile,
+* bit-expands the GF(256) coding matrix host-side into the kernel's
+  coefficient masks (cached by content),
+* tiles the payload byte axis by :func:`choose_block_b`, the last tile
+  clipped at the payload's end (no padded copy),
 * runs the Pallas kernel compiled, or in interpret mode when the caller
   passes ``interpret=True`` (the CPU tests do),
 * takes the jnp table product for payloads narrower than one lane tile
   (chosen by shape, on every backend).
+
+:func:`gf_product` is the same kernel as the SPMD repair program calls
+it on a TPU mesh, with masks it built when the program was built.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core import gf as _gf
-from .gf_matmul import gf_matmul_pallas
+from .gf_matmul import WORD_TILE, bit_expand, gf_matmul_pallas
 from .ref import gf_matmul_ref
 
 _LANE = 128
+# Payload bytes in and out of one grid step, and the widest tile: the
+# grid costs a fixed time a step, and wider tiles stop paying (chip
+# sweep, PERF.md).
+STEP_BYTES = 512 * 1024
+MAX_TILE = 65536
 
 
-@functools.lru_cache(maxsize=4096)
-def _bitmatrix_cached(key: bytes, shape: tuple[int, int]) -> np.ndarray:
-    m = np.frombuffer(key, dtype=np.uint8).reshape(shape)
-    return _gf.gf_matrix_to_bitmatrix(m).astype(np.int8)
+def gf_product(masks: jax.Array, x: jax.Array) -> jax.Array:
+    """(R, 8K) coefficient masks (a constant or traced) times a (K, B)
+    uint8 payload on the TPU: the compiled kernel, tiled by
+    :func:`choose_block_b`."""
+    return gf_matmul_pallas(
+        masks, x, block_b=choose_block_b(masks.shape[1] // 8, masks.shape[0]))
 
 
-def bit_expand(m: np.ndarray) -> np.ndarray:
-    """(R, K) GF(256) matrix -> (8R, 8K) int8 GF(2) bit-matrix (cached)."""
-    m = np.ascontiguousarray(np.asarray(m, dtype=np.uint8))
-    return _bitmatrix_cached(m.tobytes(), m.shape)
-
-
-def choose_block_b(k: int, r: int, vmem_budget: int = 8 * 2**20) -> int:
-    """Largest lane-aligned payload tile fitting the VMEM budget.
-
-    Working set per step ≈ bitplanes (8K·tb) + packed in (K·tb) + packed
-    out (R·tb) + int32 accumulator (4·8R·tb) bytes + resident matrix.
-    """
-    per_byte = 8 * k + k + r + 32 * r
-    fixed = 64 * r * k
-    tb = max(_LANE, ((vmem_budget - fixed) // per_byte) // _LANE * _LANE)
-    return int(min(tb, 4096))
+def choose_block_b(k: int, r: int) -> int:
+    """The payload tile of a (R, K) product: the power of two of bytes,
+    from ``WORD_TILE`` to ``MAX_TILE``, whose K input and R output rows
+    hold at most ``STEP_BYTES``."""
+    tb = MAX_TILE
+    while tb > WORD_TILE and tb * (k + r) > STEP_BYTES:
+        tb //= 2
+    return tb
 
 
 def gf_matmul(
@@ -100,14 +101,10 @@ def _dispatch(
 ) -> jax.Array:
     if b < _LANE:  # narrower than one lane tile: nothing to tile
         return gf_matmul_ref(jnp.asarray(m_np), x)
-    tb = block_b or choose_block_b(k, r)
-    tb = min(tb, max(_LANE, (b // _LANE) * _LANE))
-    pad = (-b) % tb
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-    mb = jnp.asarray(bit_expand(m_np))
-    y = gf_matmul_pallas(mb, x, block_b=tb, interpret=interpret)
-    return y[:, :b] if pad else y
+    masks = jnp.asarray(bit_expand(m_np))
+    return gf_matmul_pallas(masks, x,
+                            block_b=block_b or choose_block_b(k, r),
+                            interpret=interpret)
 
 
 def encode_payload(

@@ -11,7 +11,9 @@ runs it:
   blocks, S = 3 stripes;
 * the checkpoint encode of a 256 MiB state;
 * DRC(8,6,4) node recovery with one rack per chip on four chips, whose
-  compiled cross-pod permute bytes must equal the plan's Eq. (3) count.
+  compiled cross-pod permute bytes must equal the plan's Eq. (3) count;
+* the three recovery programs of the benchmark, each GF product of
+  which compiles to the Pallas kernel under its stage's scope.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -19,6 +21,7 @@ process at a time may load the TPU library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,10 +79,10 @@ def test_pallas_gf_kernel_compiles(one_chip, r, k, b):
     from repro.kernels.ops import choose_block_b
 
     tb = choose_block_b(k, r)
-    mb = jax.ShapeDtypeStruct((8 * r, 8 * k), jnp.int8, sharding=one_chip)
+    masks = jax.ShapeDtypeStruct((r, 8 * k), jnp.int32, sharding=one_chip)
     x = jax.ShapeDtypeStruct((k, b), jnp.uint8, sharding=one_chip)
     compiled = jax.jit(
-        lambda m, p: gf_matmul_pallas(m, p, block_b=tb)).lower(mb, x).compile()
+        lambda m, p: gf_matmul_pallas(m, p, block_b=tb)).lower(masks, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_LIMIT
 
@@ -129,3 +132,54 @@ def test_four_chip_drc_cross_pod_bytes_equal_eq3(topo):
     want = sum(expected_cross_units(code.repair_plan(0, rotation=s)) * sub
                for s in range(stripes))
     assert cross_pod_permute_bytes(compiled.as_text(), 1) == want
+
+
+_PAD = re.compile(r"= \S+ pad\(.*padding=([0-9_x-]+)")
+
+
+@pytest.mark.parametrize("family, n, k, r, stripes, chips, kernels", [
+    ("DRC", 9, 6, 3, 3, 1, 27),
+    ("RS", 9, 6, 3, 3, 1, 21),
+    ("DRC", 8, 6, 4, 2, 4, 7),
+], ids=["drc963_one_chip", "rs963_one_chip", "drc864_four_chips"])
+def test_recovery_gf_products_compile_to_the_kernel(
+        topo, family, n, k, r, stripes, chips, kernels):
+    """On a TPU mesh every GF product of NodeEncode, RelayerEncode and
+    Decode is one ``tpu_custom_call`` whose ``op_name`` carries its stage
+    (what the stage metrics read), as many as ``gf_products`` counts; no
+    pad copies a payload-wide array; the program fits HBM; and on four
+    chips the cross-pod bytes still equal Eq. (3).  DRC(8,6,4) holds 7:
+    per chip and stripe 2 NodeEncode, 1 RelayerEncode and 1 Decode, less
+    one NodeEncode slot whose nodes send nothing on any chip."""
+    from repro.dist.collectives import (
+        expected_cross_units, gf_path, gf_products, node_recovery_program)
+    from repro.launch.hlo_analysis import cross_pod_permute_bytes
+
+    code = make_code(family, n, k, r)
+    sub = _sub(code)
+    mesh = make_repair_mesh(r, n // r, topo.devices[:chips])
+    assert gf_path(mesh) == "pallas"
+    prog, specs = node_recovery_program(code, 0, stripes, mesh)
+    x = jax.ShapeDtypeStruct((stripes, n, code.alpha, sub), jnp.uint8,
+                             sharding=NamedSharding(mesh, P(None, ("pod", "node"))))
+    compiled = prog.lower(x).compile()
+    text = compiled.as_text()
+    want: dict[str, int] = {}
+    for sp in specs:
+        for stage, count in gf_products(sp, *mesh.devices.shape).items():
+            want[stage] = want.get(stage, 0) + count
+    got: dict[str, int] = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
+        stage = next(p for p in op_name if p in want)
+        got[stage] = got.get(stage, 0) + 1
+    assert got == want and sum(got.values()) == kernels
+    for padding in _PAD.findall(text):
+        assert padding.split("x")[-1] == "0_0", padding  # payload axis
+    assert _device_bytes(compiled) < HBM_LIMIT
+    if chips > 1:
+        eq3 = sum(expected_cross_units(code.repair_plan(0, rotation=s)) * sub
+                  for s in range(stripes))
+        assert cross_pod_permute_bytes(text, 1) == eq3

@@ -160,7 +160,7 @@ def test_spmd_repair_hlo_cross_pod_bytes_match_plan():
         """
         import jax, jax.numpy as jnp, numpy as np, json
         from repro.core.codes import make_code
-        from repro.dist.collectives import plan_to_spmd, make_spmd_repair
+        from repro.dist.collectives import gf_path, plan_to_spmd, make_spmd_repair
         from repro.launch.hlo_analysis import parse_collectives
         from jax.sharding import PartitionSpec as P
         mesh = jax.make_mesh((3,3), ('pod','node'),
@@ -171,7 +171,7 @@ def test_spmd_repair_hlo_cross_pod_bytes_match_plan():
             code = make_code(fam, n, k, r)
             plan = code.repair_plan(0)
             spec = plan_to_spmd(code, plan)
-            fn = jax.shard_map(make_spmd_repair(spec), mesh=mesh,
+            fn = jax.shard_map(make_spmd_repair(spec, gf_path(mesh)), mesh=mesh,
                                in_specs=P(('pod','node')), out_specs=P(('pod','node')))
             comp = jax.jit(fn).lower(
                 jax.ShapeDtypeStruct((code.n, code.alpha, SUB), jnp.uint8)).compile()
@@ -337,6 +337,58 @@ def test_spmd_repair_device_layouts(pods, n, k, r, family):
         devices=4,
     )
     assert "OK" in out
+
+
+@pytest.mark.parametrize("devices", [1, 3, 9], ids=[
+    "stripe_on_one_device", "rack_per_device", "node_per_device"])
+def test_cpu_repair_program_takes_jnp_product(devices):
+    """Off a TPU the repair program keeps ``gf_matmul_jnp`` in every
+    layout: its jaxpr holds one such product per GF product
+    ``gf_products`` counts and no Pallas call, its root span says
+    ``gf_path=jnp``, ``repair.gf_kernel_calls`` books 0 kernel calls by
+    stage, and the rebuilt blocks equal numpy ``RepairPlan.execute``."""
+    out = run_sub(
+        f"""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro import obs
+        from repro.core.codes import make_code
+        from repro.dist.collectives import (gf_path, gf_products,
+                                            node_recovery_program,
+                                            spmd_node_recovery)
+        from repro.launch.mesh import make_repair_mesh
+        code = make_code('DRC', 9, 6, 3)
+        mesh = make_repair_mesh(3, 3, jax.devices()[:{devices}])
+        assert gf_path(mesh) == 'jnp'
+        rng = np.random.default_rng({devices})
+        stripes = [code.encode(rng.integers(0, 256, (code.k * code.alpha, 200),
+                                            dtype=np.uint8)) for _ in range(2)]
+        x = jnp.asarray(np.stack([np.stack(s) for s in stripes]))
+        with obs.tracing('t') as tr:
+            out, specs = spmd_node_recovery(code, 0, x, mesh)
+        out = np.asarray(out)
+        for s, sp in enumerate(specs):
+            want = code.repair_plan(0, rotation=s).execute(
+                {{i: p for i, p in enumerate(stripes[s]) if i != 0}})
+            assert np.array_equal(out[s, sp.target_pod * sp.w], want), s
+        root, = tr.spans_named('repair.spmd_node_recovery')
+        assert root.attrs['gf_path'] == 'jnp', root.attrs
+        products = {{}}
+        for sp in specs:
+            for st, n in gf_products(sp, *mesh.devices.shape).items():
+                products[st] = products.get(st, 0) + n
+        for st in products:
+            assert tr.counter_value('repair.gf_kernel_calls', stage=st) == 0
+        prog, _ = node_recovery_program(code, 0, 2, mesh)
+        text = str(jax.make_jaxpr(prog)(x))
+        assert 'pallas_call' not in text
+        print('PRODUCTS', sum(products.values()),
+              text.count('name=gf_matmul_jnp'))
+        """,
+        devices=9,
+    )
+    line = next(l for l in out.splitlines() if l.startswith("PRODUCTS"))
+    want, got = map(int, line.split()[1:])
+    assert got == want > 0
 
 
 @pytest.mark.parametrize("family, missing", [("DRC", set()),

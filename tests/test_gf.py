@@ -112,33 +112,51 @@ def test_cauchy_mds():
         assert gf.gf_rank(g[rows]) == 6
 
 
+def _horner_words(masks: np.ndarray, words: np.ndarray) -> np.ndarray:
+    """The Pallas kernel's arithmetic on host arrays: for each output row
+    q, Horner's scheme over the coefficients' bits, high bit first,
+    doubling four bytes a 32-bit word with the kernel's ``_double``.
+    masks (R, 8K) int32 (``bit_expand``), words (K, W) int32."""
+    import jax.numpy as jnp
+    from repro.kernels.gf_matmul import _double
+
+    k = words.shape[0]
+    acc = jnp.zeros((masks.shape[0], words.shape[1]), jnp.int32)
+    for i in reversed(range(8)):
+        acc = _double(acc)
+        for j in range(k):
+            acc = acc ^ (jnp.asarray(words[j])[None, :]
+                         & jnp.asarray(masks[:, 8 * j + i])[:, None])
+    return np.asarray(acc)
+
+
 def test_bitmatrix_mul_equivalence():
-    rng = np.random.default_rng(1)
-    for _ in range(20):
-        c = int(rng.integers(0, 256))
-        x = int(rng.integers(0, 256))
-        m = gf.gf_mul_bitmatrix(c)
-        xbits = np.array([(x >> i) & 1 for i in range(8)], dtype=np.uint8)
-        ybits = m @ xbits % 2
-        y = int(sum(int(b) << i for i, b in enumerate(ybits)))
-        assert y == int(gf.gf_mul(c, x))
+    """The kernel's GF(2) decomposition of a multiply, c ⊗ x =
+    XOR_i bit_i(c)·(2^i ⊗ x), with c's bits as masks and x's bytes packed
+    four a word, equals ``gf_mul`` for every c and x."""
+    from repro.kernels.gf_matmul import bit_expand
+
+    cs = np.arange(256, dtype=np.uint8)
+    masks = bit_expand(cs[:, None])  # (256, 8): one 1x1 matrix a row
+    xs = np.arange(256, dtype=np.uint8)
+    got = _horner_words(masks, xs.view(np.int32)[None, :]).view(np.uint8)
+    want = np.array([[gf.gf_mul(int(c), int(x)) for x in xs] for c in cs],
+                    dtype=np.uint8)
+    np.testing.assert_array_equal(got, want)
 
 
 def test_bitmatrix_matmul_equivalence():
+    """A (4, 6) GF(256) product through the kernel's masks and doubling
+    on host arrays equals ``gf_matmul``."""
+    from repro.kernels.gf_matmul import bit_expand
+
     rng = np.random.default_rng(2)
     a = rng.integers(0, 256, size=(4, 6), dtype=np.uint8)
     x = rng.integers(0, 256, size=(6, 32), dtype=np.uint8)
     want = gf.gf_matmul(a, x)
-    abit = gf.gf_matrix_to_bitmatrix(a)  # (32, 48)
-    xbits = np.zeros((48, 32), dtype=np.uint8)
-    for j in range(6):
-        for i in range(8):
-            xbits[8 * j + i] = (x[j] >> i) & 1
-    ybits = (abit.astype(np.int32) @ xbits.astype(np.int32)) % 2
-    got = np.zeros_like(want)
-    for r in range(4):
-        for i in range(8):
-            got[r] |= (ybits[8 * r + i].astype(np.uint8)) << i
+    masks = bit_expand(a)  # (4, 48)
+    assert masks.shape == (4, 48) and masks.dtype == np.int32
+    got = _horner_words(masks, x.view(np.int32)).view(np.uint8)
     np.testing.assert_array_equal(got, want)
 
 

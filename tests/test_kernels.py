@@ -40,13 +40,14 @@ def test_kernel_matches_oracle(r, k, b):
     np.testing.assert_array_equal(want, gfnp.gf_matmul(m, x))
 
 
-@pytest.mark.parametrize("block_b", [128, 256, 512])
+@pytest.mark.parametrize("block_b", [512, 1024, 2048])
 def test_kernel_block_shapes(block_b):
     rng = np.random.default_rng(block_b)
-    m, x = _rand(rng, 6, 9, 1024)
-    mb = jnp.asarray(bit_expand(m))
+    m, x = _rand(rng, 6, 9, 2048)
+    masks = jnp.asarray(bit_expand(m))
     got = np.asarray(
-        gf_matmul_pallas(mb, jnp.asarray(x), block_b=block_b, interpret=True)
+        gf_matmul_pallas(masks, jnp.asarray(x), block_b=block_b,
+                         interpret=True)
     )
     np.testing.assert_array_equal(got, gfnp.gf_matmul(m, x))
 
@@ -56,6 +57,62 @@ def test_unaligned_payload_padding():
     m, x = _rand(rng, 3, 6, 333)  # not a multiple of 128
     got = np.asarray(gf_matmul(m, jnp.asarray(x), interpret=True))
     np.testing.assert_array_equal(got, gfnp.gf_matmul(m, x))
+
+
+# The coefficient matrices' shapes of each cell's GF products: DRC(9,6,3)
+# NodeEncode (3,3) and RelayerEncode/Decode (3,12); RS(9,6,3) NodeEncode
+# (1,1) and Decode (1,6); DRC(8,6,4) NodeEncode (2,2), RelayerEncode
+# (2,6) and Decode (2,8).
+CELL_PRODUCTS = [(3, 3), (3, 12), (1, 1), (1, 6), (2, 2), (2, 6), (2, 8)]
+TILE = 512  # widths of 128 x an odd number below, near and above one tile
+RAGGED_WIDTHS = [128 * 3, 128 * 5, 128 * 11]
+
+
+@pytest.mark.parametrize("matrix", ["random", "zero"])
+@pytest.mark.parametrize("b", RAGGED_WIDTHS, ids=["below", "near", "above"])
+@pytest.mark.parametrize("r,k", CELL_PRODUCTS)
+def test_ragged_grid_kernel_matches_jnp_and_numpy(r, k, b, matrix):
+    """A ragged grid, its last tile clipped at the payload's end (a tile
+    wider than the whole payload included), gives the bytes of
+    gf_matmul_jnp and of the numpy reference exactly."""
+    from repro.core.gf_jax import gf_matmul_jnp
+
+    rng = np.random.default_rng(r * 100 + k * 10 + b)
+    m, x = _rand(rng, r, k, b)
+    if matrix == "zero":
+        m = np.zeros_like(m)
+    got = np.asarray(gf_matmul_pallas(jnp.asarray(bit_expand(m)),
+                                      jnp.asarray(x), block_b=TILE,
+                                      interpret=True))
+    want = gfnp.gf_matmul(m, x)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        np.asarray(gf_matmul_jnp(jnp.asarray(m), jnp.asarray(x))), want)
+
+
+def test_stacked_bitmatrix_indexed_by_traced_node():
+    """The four-chip path: stacked bit-matrices picked by a traced index
+    and handed to the kernel as an operand, so one compiled kernel serves
+    every node of the shape."""
+    import jax
+
+    rng = np.random.default_rng(11)
+    mats = rng.integers(0, 256, size=(4, 2, 6), dtype=np.uint8)
+    mats[2] = 0  # a node with nothing to send
+    x = rng.integers(0, 256, size=(6, 128 * 5), dtype=np.uint8)
+    stack = jnp.asarray(bit_expand(mats))
+    assert stack.shape == (4, 2, 48)
+
+    @jax.jit
+    def pick(node, x):
+        masks = jax.lax.dynamic_index_in_dim(stack, node, 0, keepdims=False)
+        return gf_matmul_pallas(masks, x, block_b=512, interpret=True)
+
+    for node in range(4):
+        got = np.asarray(pick(jnp.int32(node), jnp.asarray(x)))
+        np.testing.assert_array_equal(got, gfnp.gf_matmul(mats[node], x))
+    # one trace serves every node
+    assert pick._cache_size() == 1
 
 
 def test_small_payload_fallback():
@@ -103,17 +160,28 @@ def test_encode_payload_systematic():
 
 
 def test_choose_block_b_bounds():
-    for k, r in [(1, 1), (18, 27), (162, 27), (512, 64)]:
+    from repro.kernels.gf_matmul import WORD_TILE
+    from repro.kernels.ops import MAX_TILE, STEP_BYTES
+
+    for k, r in [(1, 1), (18, 27), (162, 27), (512, 64), (4096, 4096),
+                 *[(k, r) for r, k in CELL_PRODUCTS]]:
         tb = choose_block_b(k, r)
-        assert tb % 128 == 0 and 128 <= tb <= 4096
+        assert tb % WORD_TILE == 0 and WORD_TILE <= tb <= MAX_TILE
+        assert tb * (k + r) <= STEP_BYTES or tb == WORD_TILE
+        assert tb == MAX_TILE or 2 * tb * (k + r) > STEP_BYTES
 
 
 def test_bit_expand_roundtrip_semantics():
+    """One all-ones or all-zeros 32-bit mask per coefficient bit, in
+    (row, 8 * column + bit) order; a stack stays a stack."""
     rng = np.random.default_rng(9)
     m = rng.integers(0, 256, size=(5, 7), dtype=np.uint8)
-    mb = bit_expand(m)
-    assert mb.shape == (40, 56) and mb.dtype == np.int8
-    assert set(np.unique(mb)) <= {0, 1}
+    masks = bit_expand(m)
+    assert masks.shape == (5, 56) and masks.dtype == np.int32
+    assert set(np.unique(masks)) <= {0, -1}
+    bits = (masks.reshape(5, 7, 8) != 0) << np.arange(8)
+    np.testing.assert_array_equal(bits.sum(axis=-1), m)
+    assert bit_expand(np.stack([m, m])).shape == (2, 5, 56)
 
 
 if __name__ == "__main__":

@@ -218,16 +218,74 @@ def test_kernel_geometry_is_what_pallas_call_consumes():
     assert geom.grid == (8,)
     in_specs = geom.in_specs()
     assert len(in_specs) == 2
+    assert in_specs[0].block_shape == (3, 48)  # the coefficient masks
     assert geom.out_spec().block_shape == (3, 512)
 
 
 def test_kernel_geometry_rejects_indivisible_payload():
+    """A payload width that is no multiple of the tile gets a ragged
+    grid, its last block clipped at the end, which the rules prove in
+    bounds and write-disjoint; a tile that is no multiple of 512 bytes
+    (four lane-rows of 32-bit words) is still refused."""
+    geom = gf_matmul_geometry(3, 6, 1000, 512)
+    assert geom.grid == (2,) and geom.clipped_dim == 1
+    assert pallas.analyze_geometry(geom) == []
     with pytest.raises(ValueError, match="not a multiple"):
-        gf_matmul_geometry(3, 6, 1000, 512)
+        gf_matmul_geometry(3, 6, 1000, 384)
+    # a tile wider than the payload shrinks to it, rounded up to 512
+    geom = gf_matmul_geometry(3, 6, 200, 4096)
+    assert geom.out_block == (3, 512) and geom.grid == (1,)
+    assert pallas.analyze_geometry(geom) == []
+
+
+def test_geometry_sweep_tiles_the_cells_as_the_kernel_does():
+    """The swept shapes at the cells' widths carry the tile the kernel
+    takes for them, so the sweep proves the geometry that runs."""
+    from repro.kernels.ops import choose_block_b
+
+    real = [s for s in pallas.GEOMETRY_SHAPES if s[2] >= 349568]
+    assert len(real) == 6
+    for r, k, b, tile in real:
+        assert tile == choose_block_b(k, r), (r, k, b)
+        assert gf_matmul_geometry(r, k, b, tile).grid[0] > 1
+
+
+def test_pallas_oob_clips_only_the_declared_dim():
+    """The clip is a property the geometry declares, not a looser rule:
+    the same ragged blocks without ``clipped_dim`` run out of bounds."""
+    geom = gf_matmul_geometry(3, 6, 1000, 512)
+    strict = dataclasses.replace(geom, clipped_dim=None)
+    findings = pallas.check_pallas_oob(strict)
+    assert findings and findings[0].witness["start"] == 512
+    assert findings[0].witness["extent"] == 1000
+    # and a clipped dim still keeps every block's start inside the array
+    assert pallas.check_pallas_oob(geom) == []
+
+
+@pytest.mark.parametrize("mutation, owner", [
+    ("pallas_clip_past_end", pallas.R_PL_OOB),
+    ("pallas_clip_overlap", pallas.R_PL_ALIAS),
+])
+def test_clipped_geometry_mutation_caught_by_owner_alone(mutation, owner):
+    """At a cell's real width (DRC(9,6,3) Decode over a 22,369,664-byte
+    sub-block, 128 x a prime), a last block shifted past the end fails
+    the oob rule alone, and two overlapping blocks the alias rule alone."""
+    from repro.kernels.ops import choose_block_b
+
+    geom = gf_matmul_geometry(3, 12, 22369664, choose_block_b(12, 3))
+    assert 22369664 % geom.out_block[1] and pallas.analyze_geometry(geom) == []
+    kind, bad = pallas.mutate_pallas(geom, "", mutation)
+    assert kind == "geometry"
+    findings = pallas.analyze_geometry(bad)
+    assert {f.rule for f in findings} == {owner}
+    if owner == pallas.R_PL_OOB:
+        assert findings[0].witness["start"] >= 22369664
+    else:
+        assert findings[0].witness["second"] == [geom.grid[0] - 1]
 
 
 def test_pallas_oob_witness_names_grid_point_and_extent():
-    geom = gf_matmul_geometry(2, 4, 1024, 256)
+    geom = gf_matmul_geometry(2, 4, 1024, 512)
     bad = dataclasses.replace(
         geom,
         in_index_maps=(geom.in_index_maps[0], lambda j: (0, j + 1)),
@@ -238,7 +296,7 @@ def test_pallas_oob_witness_names_grid_point_and_extent():
 
 
 def test_pallas_alias_detects_constant_out_map():
-    geom = gf_matmul_geometry(2, 4, 1024, 256)
+    geom = gf_matmul_geometry(2, 4, 1024, 512)
     bad = dataclasses.replace(geom, out_index_map=lambda j: (0, 0))
     findings = pallas.check_pallas_out_alias(bad)
     assert findings and "write-write race" in findings[0].message
