@@ -7,10 +7,11 @@ system starts on the chip and rebuilds the right bytes.
 
 One chip runs three phases through the normal entry points:
 
-* ``node_recovery`` -- DRC(9,6,3) (the paper's section-6 testbed policy)
-  and RS(9,6,3) (HDFS-EC RS-6-3) rebuild failed node 0 of S = 3 stripes
-  of 64 MiB blocks in one ``spmd_node_recovery`` program, the whole
-  stripe on one chip;
+* ``node_recovery`` -- DRC(9,6,3) (the paper's section-6 testbed policy),
+  RS(9,6,3) (HDFS-EC RS-6-3) and Clay MSR(9,6,3) (alpha 27, Ceph's clay
+  plugin with k=6, m=3, d=8) rebuild failed node 0 of S = 3 stripes of
+  64 MiB blocks in one ``spmd_node_recovery`` program, the whole stripe
+  on one chip;
 * ``checkpoint`` -- a 256 MiB float32 state is saved with
   ``CheckpointManager``, one node file is deleted, and it is loaded back;
 * ``kernel`` -- the compiled Pallas GF(2^8) product with the DRC(9,6,3)
@@ -147,7 +148,7 @@ def phase_node_recovery(devices, seed: int) -> None:
     from repro.launch.mesh import make_repair_mesh
 
     failed, n_stripes = 0, 3
-    for family in ("DRC", "RS"):
+    for family in ("DRC", "RS", "MSR"):
         code = make_code(family, 9, 6, 3)
         mesh = make_repair_mesh(code.r, code.n // code.r, devices[:1])
         label = f"{family}(9,6,3)"

@@ -80,7 +80,9 @@ def gf_matmul(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     """Matrix product over GF(256): (m,k) x (k,p) -> (m,p).
 
     XOR-accumulation of table products.  Vectorized over the output row: for
-    plan-time sizes (<= a few thousand) this is plenty fast.
+    plan-time sizes (<= a few thousand) this is plenty fast.  Rows whose
+    coefficient is zero are skipped, so a sparse matrix (a sub-block
+    selection, a coupled-layer code's parity) costs its nonzeros.
     """
     a = np.asarray(a, dtype=np.uint8)
     b = np.asarray(b, dtype=np.uint8)
@@ -92,7 +94,9 @@ def gf_matmul(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     for j in range(k):  # rank-1 updates: table[a[:,j]][:,None] "times" b[j,:]
         col = a[:, j]
         row = b[j, :]
-        out ^= GF_MUL_TABLE[col[:, None], row[None, :]]
+        rows = np.flatnonzero(col)
+        if rows.size:
+            out[rows] ^= GF_MUL_TABLE[col[rows, None], row[None, :]]
     return out
 
 

@@ -301,18 +301,26 @@ def _runs_product(mats: np.ndarray, held: list[int]) -> bool:
     return bool(mats[held].any())
 
 
-def gf_products(spec: SpmdRepairSpec, pods: int, nodes: int) -> dict[str, int]:
-    """GF products one device's repair program runs for the stripe, by
-    stage, on a ``(pods, nodes)`` mesh: what :func:`make_spmd_repair`
-    emits, as it emits them."""
+def _stage_matrices(spec: SpmdRepairSpec, pods: int, nodes: int
+                    ) -> dict[str, list[np.ndarray]]:
+    """For each GF product one device's repair program runs for the
+    stripe, by stage, the matrices it may apply: one per device of its
+    slot, on a ``(pods, nodes)`` mesh."""
     slots = [held for rack in _node_ids(spec, pods, nodes) for held in rack]
-    out = {"node_encode": sum(_runs_product(spec.node_mats, held)
-                              for held in slots)}
+    out = {"node_encode": [spec.node_mats[held] for held in slots
+                           if _runs_product(spec.node_mats, held)]}
     if spec.ru:
-        out["relayer_encode"] = sum(_runs_product(spec.relayer_mats, held)
-                                    for held in slots)
-    out["decode"] = 1
+        out["relayer_encode"] = [spec.relayer_mats[held] for held in slots
+                                 if _runs_product(spec.relayer_mats, held)]
+    out["decode"] = [spec.decode[None]]
     return out
+
+
+def _is_selection(mats: np.ndarray) -> bool:
+    # a 0/1 row selection: every row copies at most one input row
+    # unchanged, so the product is a row take
+    return bool((mats <= 1).all()
+                and (np.count_nonzero(mats, axis=-1) <= 1).all())
 
 
 def make_spmd_repair(spec: SpmdRepairSpec, path: str) -> Callable[[Any], Any]:
@@ -473,12 +481,44 @@ def _record_schedule(spec: SpmdRepairSpec, sub_bytes: int) -> None:
             obs.counter_add("repair.units_cross", len(rows), pod=str(q))
 
 
-def _record_gf(path: str, products: dict[str, int]) -> None:
-    """Book the call's GF products that run as the Pallas kernel, by
-    stage (none on the ``jnp`` path)."""
-    for stage, count in products.items():
+@dataclasses.dataclass(frozen=True)
+class GfCounts:
+    """What one call's GF products do on one device, summed over the
+    call's stripes: by stage, the products :func:`make_spmd_repair`
+    emits, as it emits them, and the selections among them, whose every
+    matrix, on every device of the slot, only copies sub-blocks (as an
+    optimal-access helper's send does); and the sub-blocks the helpers'
+    NodeEncode matrices read, their nonzero columns."""
+
+    products: dict[str, int]
+    selections: dict[str, int]
+    sub_blocks_read: int
+
+    @classmethod
+    def of(cls, specs: list[SpmdRepairSpec], pods: int, nodes: int
+           ) -> "GfCounts":
+        products: dict[str, int] = {}
+        selections: dict[str, int] = {}
+        for spec in specs:
+            for stage, mats in _stage_matrices(spec, pods, nodes).items():
+                products[stage] = products.get(stage, 0) + len(mats)
+                selections[stage] = (selections.get(stage, 0)
+                                     + sum(map(_is_selection, mats)))
+        return cls(products, selections, sum(
+            int(np.count_nonzero(spec.node_mats.any(axis=1)))
+            for spec in specs))
+
+
+def _record_gf(path: str, counts: GfCounts) -> None:
+    """Book the call's GF products that run as the Pallas kernel (none
+    on the ``jnp`` path) and those that are selections, by stage, and the
+    sub-blocks NodeEncode reads."""
+    for stage, count in counts.products.items():
         obs.counter_add("repair.gf_kernel_calls",
                         count if path == "pallas" else 0, stage=stage)
+        obs.counter_add("repair.gf_selection_calls",
+                        counts.selections[stage], stage=stage)
+    obs.counter_add("repair.helper_sub_blocks_read", counts.sub_blocks_read)
 
 
 def spmd_repair(
@@ -513,21 +553,21 @@ def spmd_repair(
         root.set_attr("sub_bytes", sub_bytes)
         root.set_attr("gf_path", path)
         _record_schedule(spec, sub_bytes)
-        _record_gf(path, gf_products(spec, *mesh.devices.shape))
+        _record_gf(path, GfCounts.of([spec], *mesh.devices.shape))
         with obs.span("repair.launch", cat="repair"):
             out = jit_fn(payloads)
     return out, spec
 
 
 # One jitted program per (code, failed node, stripe count, mesh), with
-# its GF path and the GF products one device runs a call, by stage.
+# its GF path and what its GF products do on one device a call.
 _RECOVERY_PROGRAMS: dict[tuple[str, int, int, Any],
-                         tuple[Any, str, dict[str, int]]] = {}
+                         tuple[Any, str, GfCounts]] = {}
 
 
 def _recovery_program(
     code: ErasureCode, failed: int, n_stripes: int, mesh: Any
-) -> tuple[Any, list[SpmdRepairSpec], str, dict[str, int]]:
+) -> tuple[Any, list[SpmdRepairSpec], str, GfCounts]:
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -541,10 +581,7 @@ def _recovery_program(
     if built is None:
         path = gf_path(mesh)
         bodies = [make_spmd_repair(spec, path) for spec in specs]
-        products: dict[str, int] = {}
-        for spec in specs:
-            for stage, count in gf_products(spec, *mesh.devices.shape).items():
-                products[stage] = products.get(stage, 0) + count
+        counts = GfCounts.of(specs, *mesh.devices.shape)
 
         def body(x: Any) -> Any:  # (S, racks*nodes, alpha, sub) per device
             # a stripe's rows are NodeEncode's input: its split from the
@@ -562,9 +599,9 @@ def _recovery_program(
             in_specs=P(None, ("pod", "node")),
             out_specs=P(None, ("pod", "node")),
         ))
-        built = _RECOVERY_PROGRAMS[key] = (prog, path, products)
-    prog, path, products = built
-    return prog, specs, path, products
+        built = _RECOVERY_PROGRAMS[key] = (prog, path, counts)
+    prog, path, counts = built
+    return prog, specs, path, counts
 
 
 def node_recovery_program(
@@ -592,24 +629,30 @@ def spmd_node_recovery(
     across the helper nodes of each remote rack (paper §5.2: node-level
     repair load balance).  Returns ((S, n, alpha, sub), specs).
 
-    The root span carries ``gf_path`` (:func:`gf_path`), and the
-    counter ``repair.gf_kernel_calls`` counts, by stage, the GF products
-    of the call that run as the Pallas kernel.
+    The root span carries ``gf_path`` (:func:`gf_path`), ``alpha`` and
+    ``sub_bytes``.  By stage, the counter ``repair.gf_kernel_calls``
+    counts the GF products of the call that run as the Pallas kernel,
+    and ``repair.gf_selection_calls`` those that only select sub-blocks;
+    ``repair.helper_sub_blocks_read`` counts the sub-blocks their
+    NodeEncode reads (:class:`GfCounts`, worked out when the program is
+    built).
     """
     n_stripes = int(payloads.shape[0])
     sub_bytes = int(payloads.shape[-1])
     with obs.span("repair.spmd_node_recovery", cat="repair", failed=failed,
                   stripes=n_stripes) as root:
         with obs.span("repair.plan", cat="repair"):
-            prog, specs, path, products = _recovery_program(
+            prog, specs, path, counts = _recovery_program(
                 code, failed, n_stripes, mesh)
         root.set_attr("family", specs[0].family if specs else "")
+        root.set_attr("alpha", code.alpha)
+        root.set_attr("sub_bytes", sub_bytes)
         root.set_attr("distinct_relayer_sets", len(
             {tuple(sp.rel_idx.tolist()) for sp in specs}))
         root.set_attr("gf_path", path)
         for spec in specs:
             _record_schedule(spec, sub_bytes)
-        _record_gf(path, products)
+        _record_gf(path, counts)
         with obs.span("repair.launch", cat="repair"):
             out = prog(payloads)
     return out, specs

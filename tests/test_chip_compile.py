@@ -12,7 +12,7 @@ runs it:
 * the checkpoint encode of a 256 MiB state;
 * DRC(8,6,4) node recovery with one rack per chip on four chips, whose
   compiled cross-pod permute bytes must equal the plan's Eq. (3) count;
-* the three recovery programs of the benchmark, each GF product of
+* the four recovery programs of the benchmark, each GF product of
   which compiles to the Pallas kernel under its stage's scope.
 
 The topology is described inside a fixture, never at import: only one
@@ -141,18 +141,22 @@ _PAD = re.compile(r"= \S+ pad\(.*padding=([0-9_x-]+)")
     ("DRC", 9, 6, 3, 3, 1, 27),
     ("RS", 9, 6, 3, 3, 1, 21),
     ("DRC", 8, 6, 4, 2, 4, 7),
-], ids=["drc963_one_chip", "rs963_one_chip", "drc864_four_chips"])
+    ("MSR", 9, 6, 3, 3, 1, 27),
+], ids=["drc963_one_chip", "rs963_one_chip", "drc864_four_chips",
+        "msr963_one_chip"])
 def test_recovery_gf_products_compile_to_the_kernel(
         topo, family, n, k, r, stripes, chips, kernels):
     """On a TPU mesh every GF product of NodeEncode, RelayerEncode and
     Decode is one ``tpu_custom_call`` whose ``op_name`` carries its stage
-    (what the stage metrics read), as many as ``gf_products`` counts; no
+    (what the stage metrics read), as many as ``GfCounts`` counts; no
     pad copies a payload-wide array; the program fits HBM; and on four
     chips the cross-pod bytes still equal Eq. (3).  DRC(8,6,4) holds 7:
     per chip and stripe 2 NodeEncode, 1 RelayerEncode and 1 Decode, less
-    one NodeEncode slot whose nodes send nothing on any chip."""
+    one NodeEncode slot whose nodes send nothing on any chip.  MSR(9,6,3)
+    (alpha 27, sub-blocks of 2,485,632 B) holds 27: per stripe 8 NodeEncode
+    of 9 x 27 and one 27 x 72 Decode.  On one chip no collective runs."""
     from repro.dist.collectives import (
-        expected_cross_units, gf_path, gf_products, node_recovery_program)
+        GfCounts, expected_cross_units, gf_path, node_recovery_program)
     from repro.launch.hlo_analysis import cross_pod_permute_bytes
 
     code = make_code(family, n, k, r)
@@ -164,10 +168,7 @@ def test_recovery_gf_products_compile_to_the_kernel(
                              sharding=NamedSharding(mesh, P(None, ("pod", "node"))))
     compiled = prog.lower(x).compile()
     text = compiled.as_text()
-    want: dict[str, int] = {}
-    for sp in specs:
-        for stage, count in gf_products(sp, *mesh.devices.shape).items():
-            want[stage] = want.get(stage, 0) + count
+    want = GfCounts.of(specs, *mesh.devices.shape).products
     got: dict[str, int] = {}
     for line in text.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
@@ -179,7 +180,9 @@ def test_recovery_gf_products_compile_to_the_kernel(
     for padding in _PAD.findall(text):
         assert padding.split("x")[-1] == "0_0", padding  # payload axis
     assert _device_bytes(compiled) < HBM_LIMIT
-    if chips > 1:
+    if chips == 1:
+        assert "collective-permute" not in text
+    else:
         eq3 = sum(expected_cross_units(code.repair_plan(0, rotation=s)) * sub
                   for s in range(stripes))
         assert cross_pod_permute_bytes(text, 1) == eq3

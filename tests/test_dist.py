@@ -344,7 +344,7 @@ def test_spmd_repair_device_layouts(pods, n, k, r, family):
 def test_cpu_repair_program_takes_jnp_product(devices):
     """Off a TPU the repair program keeps ``gf_matmul_jnp`` in every
     layout: its jaxpr holds one such product per GF product
-    ``gf_products`` counts and no Pallas call, its root span says
+    ``GfCounts`` counts and no Pallas call, its root span says
     ``gf_path=jnp``, ``repair.gf_kernel_calls`` books 0 kernel calls by
     stage, and the rebuilt blocks equal numpy ``RepairPlan.execute``."""
     out = run_sub(
@@ -352,7 +352,7 @@ def test_cpu_repair_program_takes_jnp_product(devices):
         import jax, jax.numpy as jnp, numpy as np
         from repro import obs
         from repro.core.codes import make_code
-        from repro.dist.collectives import (gf_path, gf_products,
+        from repro.dist.collectives import (GfCounts, gf_path,
                                             node_recovery_program,
                                             spmd_node_recovery)
         from repro.launch.mesh import make_repair_mesh
@@ -372,10 +372,7 @@ def test_cpu_repair_program_takes_jnp_product(devices):
             assert np.array_equal(out[s, sp.target_pod * sp.w], want), s
         root, = tr.spans_named('repair.spmd_node_recovery')
         assert root.attrs['gf_path'] == 'jnp', root.attrs
-        products = {{}}
-        for sp in specs:
-            for st, n in gf_products(sp, *mesh.devices.shape).items():
-                products[st] = products.get(st, 0) + n
+        products = GfCounts.of(specs, *mesh.devices.shape).products
         for st in products:
             assert tr.counter_value('repair.gf_kernel_calls', stage=st) == 0
         prog, _ = node_recovery_program(code, 0, 2, mesh)
@@ -389,6 +386,116 @@ def test_cpu_repair_program_takes_jnp_product(devices):
     line = next(l for l in out.splitlines() if l.startswith("PRODUCTS"))
     want, got = map(int, line.split()[1:])
     assert got == want > 0
+
+
+# Clay MSR(9,6,3) through node recovery at S = 3 (rotations 0-2): one
+# device's GF products a call, by stage, and the selections among them,
+# in each layout.  Every helper sends 9 of its 27 sub-blocks raw.
+MSR_LAYOUTS = {
+    1: ({"node_encode": 24, "decode": 3}, {"node_encode": 24, "decode": 0}),
+    3: ({"node_encode": 9, "decode": 3}, {"node_encode": 9, "decode": 0}),
+    9: ({"node_encode": 3, "decode": 3}, {"node_encode": 3, "decode": 0}),
+}
+
+
+@pytest.mark.parametrize("devices", [1, 3, 9], ids=[
+    "stripe_on_one_device", "rack_per_device", "node_per_device"])
+def test_msr_node_recovery_matches_numpy_in_every_layout(devices):
+    """MSR(9,6,3), alpha 27, rebuilds node 0 of three stripes with 1 KiB
+    sub-blocks on the (1, 1), (3, 1) and (3, 3) meshes: every rebuilt
+    block equals the encoded payload and numpy ``RepairPlan.execute``
+    byte for byte, every other output row is zero, and the call books
+    its GF products, its selections and the 216 helper sub-blocks it
+    reads (8 helpers x 9 of 27, a stripe) with ``alpha`` and
+    ``sub_bytes`` on its root span."""
+    out = run_sub(
+        f"""
+        import json
+        import jax, jax.numpy as jnp, numpy as np
+        from repro import obs
+        from repro.core.codes import make_code
+        from repro.dist.collectives import GfCounts, spmd_node_recovery
+        from repro.launch.mesh import make_repair_mesh
+        code = make_code('MSR', 9, 6, 3)
+        assert code.alpha == 27
+        mesh = make_repair_mesh(3, 3, jax.devices()[:{devices}])
+        rng = np.random.default_rng({devices})
+        stripes = [code.encode(rng.integers(0, 256, (code.k * code.alpha, 1024),
+                                            dtype=np.uint8)) for _ in range(3)]
+        x = jnp.asarray(np.stack([np.stack(s) for s in stripes]))
+        with obs.tracing('t') as tr:
+            out, specs = spmd_node_recovery(code, 0, x, mesh)
+        out = np.asarray(out)
+        for s, sp in enumerate(specs):
+            row = sp.target_pod * sp.w
+            want = code.repair_plan(0, rotation=s).execute(
+                {{i: p for i, p in enumerate(stripes[s]) if i != 0}})
+            assert np.array_equal(out[s, row], stripes[s][0]), s
+            assert np.array_equal(out[s, row], want), s
+            assert not np.delete(out[s], row, axis=0).any(), s
+        root, = tr.spans_named('repair.spmd_node_recovery')
+        counts = GfCounts.of(specs, *mesh.devices.shape)
+        booked = {{st: [tr.counter_value(name, stage=st) for name in (
+            'repair.gf_kernel_calls', 'repair.gf_selection_calls')]
+            for st in counts.products}}
+        print('COUNTS', json.dumps([
+            counts.products, counts.selections, counts.sub_blocks_read,
+            booked, tr.counter_value('repair.helper_sub_blocks_read'),
+            root.attrs['alpha'], root.attrs['sub_bytes']]))
+        """,
+        devices=9,
+    )
+    line = next(l for l in out.splitlines() if l.startswith("COUNTS"))
+    products, selections, reads, booked, booked_reads, alpha, sub = json.loads(
+        line.split(" ", 1)[1])
+    assert (products, selections) == MSR_LAYOUTS[devices]
+    assert reads == booked_reads == 216 and (alpha, sub) == (27, 1024)
+    # off a TPU no product runs as the kernel; the selections are booked
+    assert booked == {st: [0, selections[st]] for st in products}
+
+
+@pytest.mark.parametrize("family, selections, reads", [
+    ("MSR", 24, 216), ("DRC", 6, 54), ("RS", 18, 18)])
+def test_node_encode_selections_and_reads_are_booked(family, selections, reads):
+    """The (9,6,3) codes on one device at S = 3: the NodeEncode products
+    that only select sub-blocks (MSR all 24, DRC the 6 of nodes 1 and 2,
+    RS all 18), none of the RelayerEncode or Decode products, and the
+    sub-blocks NodeEncode reads (MSR 9 of 27 from each of 8 helpers, DRC
+    all 3 from 6 senders, RS 1 from 6), booked when the program is built
+    and again on each call."""
+    from repro import obs
+    from repro.core.codes import make_code
+    from repro.dist import collectives
+    from repro.launch.mesh import make_repair_mesh
+
+    code = make_code(family, 9, 6, 3)
+    mesh = make_repair_mesh(3, 3, jax.devices()[:1])
+    rng = np.random.default_rng(len(family))
+    stripes = [code.encode(rng.integers(0, 256, (code.k * code.alpha, 256),
+                                        dtype=np.uint8)) for _ in range(3)]
+    x = jax.numpy.asarray(np.stack([np.stack(s) for s in stripes]))
+    for calls in (1, 2):
+        with obs.tracing("t") as tr:
+            for _ in range(calls):
+                out, specs = collectives.spmd_node_recovery(code, 0, x, mesh)
+        for s, sp in enumerate(specs):
+            assert np.array_equal(np.asarray(out)[s, sp.target_pod * sp.w],
+                                  stripes[s][0])
+        counts = collectives.GfCounts.of(specs, 1, 1)
+        assert counts.selections["node_encode"] == selections
+        assert counts.sub_blocks_read == reads
+        assert tr.counter_value("repair.gf_selection_calls",
+                                stage="node_encode") == calls * selections
+        for stage in set(counts.products) - {"node_encode"}:
+            assert tr.counter_value("repair.gf_selection_calls",
+                                    stage=stage) == 0
+        assert tr.counter_value("repair.helper_sub_blocks_read") == calls * reads
+        for root in tr.spans_named("repair.spmd_node_recovery"):
+            assert root.attrs["alpha"] == code.alpha
+            assert root.attrs["sub_bytes"] == 256
+    # worked out once, in the program's cache entry
+    key = (repr(code), 0, 3, mesh)
+    assert collectives._RECOVERY_PROGRAMS[key][2] == counts
 
 
 @pytest.mark.parametrize("family, missing", [("DRC", set()),

@@ -66,12 +66,24 @@ def test_unaligned_payload_padding():
 CELL_PRODUCTS = [(3, 3), (3, 12), (1, 1), (1, 6), (2, 2), (2, 6), (2, 8)]
 TILE = 512  # widths of 128 x an odd number below, near and above one tile
 RAGGED_WIDTHS = [128 * 3, 128 * 5, 128 * 11]
+# MSR(9,6,3)'s NodeEncode (9,27) and Decode (27,72), the widest products,
+# at the tile the program picks for them: over 97 lanes, which leaves a
+# ragged last tile, and over exactly one tile.
+MSR_PRODUCTS = [(9, 27), (27, 72)]
+RAGGED_CASES = [
+    pytest.param(r, k, b, TILE, id=f"{r}-{k}-{name}")
+    for r, k in CELL_PRODUCTS
+    for name, b in zip(["below", "near", "above"], RAGGED_WIDTHS)
+] + [
+    pytest.param(r, k, b, choose_block_b(k, r), id=f"{r}-{k}-{name}")
+    for r, k in MSR_PRODUCTS
+    for name, b in [("lanes97", 128 * 97), ("one_tile", choose_block_b(k, r))]
+]
 
 
 @pytest.mark.parametrize("matrix", ["random", "zero"])
-@pytest.mark.parametrize("b", RAGGED_WIDTHS, ids=["below", "near", "above"])
-@pytest.mark.parametrize("r,k", CELL_PRODUCTS)
-def test_ragged_grid_kernel_matches_jnp_and_numpy(r, k, b, matrix):
+@pytest.mark.parametrize("r,k,b,tile", RAGGED_CASES)
+def test_ragged_grid_kernel_matches_jnp_and_numpy(r, k, b, tile, matrix):
     """A ragged grid, its last tile clipped at the payload's end (a tile
     wider than the whole payload included), gives the bytes of
     gf_matmul_jnp and of the numpy reference exactly."""
@@ -82,7 +94,7 @@ def test_ragged_grid_kernel_matches_jnp_and_numpy(r, k, b, matrix):
     if matrix == "zero":
         m = np.zeros_like(m)
     got = np.asarray(gf_matmul_pallas(jnp.asarray(bit_expand(m)),
-                                      jnp.asarray(x), block_b=TILE,
+                                      jnp.asarray(x), block_b=tile,
                                       interpret=True))
     want = gfnp.gf_matmul(m, x)
     np.testing.assert_array_equal(got, want)
@@ -164,7 +176,7 @@ def test_choose_block_b_bounds():
     from repro.kernels.ops import MAX_TILE, STEP_BYTES
 
     for k, r in [(1, 1), (18, 27), (162, 27), (512, 64), (4096, 4096),
-                 *[(k, r) for r, k in CELL_PRODUCTS]]:
+                 *[(k, r) for r, k in CELL_PRODUCTS + MSR_PRODUCTS]]:
         tb = choose_block_b(k, r)
         assert tb % WORD_TILE == 0 and WORD_TILE <= tb <= MAX_TILE
         assert tb * (k + r) <= STEP_BYTES or tb == WORD_TILE
