@@ -56,7 +56,7 @@ def run_lowered_sweep() -> list[LoweredRecord]:
     from repro.configs import ARCHS, get_config
     from repro.core.codes.registry import make_code
     from repro.dist.sharding import MODES
-    from repro.kernels.gf_matmul import gf_matmul_geometry
+    from repro.kernels.gf_matmul import gf_matmul_geometry, operand_geometry
 
     records: list[LoweredRecord] = []
     for fam, n, k, r in LOWERED_SWEEP[SPMD_FAMILY]:
@@ -69,6 +69,10 @@ def run_lowered_sweep() -> list[LoweredRecord]:
     for shape in LOWERED_SWEEP[PALLAS_FAMILY]:
         records.append(
             pallas.verify_kernel_geometry(gf_matmul_geometry(*shape))
+        )
+    for shape in pallas.OPERAND_SHAPES:
+        records.append(
+            pallas.verify_kernel_geometry(operand_geometry(*shape))
         )
     for path in pallas.kernel_source_paths():
         records.append(pallas.verify_kernel_source(path))

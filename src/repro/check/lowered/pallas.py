@@ -7,8 +7,9 @@ not exercise faithfully: BlockSpec index maps over the real grid) and
 the dtype discipline statically, for every registered shape:
 
 * ``lowered.pallas.oob`` — every operand's index map is evaluated at
-  every grid point; ``index * block_shape`` must stay inside the full
-  array for each dimension.  Pallas silently clamps or wraps
+  every grid point, with the scalar-prefetch vector (the sources'
+  leading indices) the geometry holds; ``index * block_shape`` must stay
+  inside the full array for each dimension, a squeezed one included.  Pallas silently clamps or wraps
   out-of-bounds blocks depending on backend — a wrong index map
   corrupts payloads without crashing.  Along the geometry's
   ``clipped_dim`` (a ragged grid) a block may run past the array's end,
@@ -40,6 +41,8 @@ import itertools
 import math
 from typing import Any, Iterable, Sequence
 
+import numpy as np
+
 from ..report import FAIL, Finding, LoweredRecord
 from .base import PALLAS_FAMILY, rule
 
@@ -57,10 +60,26 @@ def _grid_points(grid: Sequence[int]) -> Iterable[tuple[int, ...]]:
     return itertools.product(*(range(g) for g in grid))
 
 
-def _extent(geom: Any, d: int, start: int, blk: int, dim: int) -> int:
-    """End of a block's elements along dim d: clipped at the array's
-    end along the geometry's ``clipped_dim``, else the block's own."""
-    return min(start + blk, dim) if d == geom.clipped_dim else start + blk
+def _extent(geom: Any, d: int, rank: int, start: int, blk: int, dim: int
+            ) -> int:
+    """End of a block's elements along dim d of a rank-``rank`` array:
+    clipped at the array's end along the geometry's ``clipped_dim``
+    (negative: counted from the last), else the block's own."""
+    clipped = geom.clipped_dim is not None and d == geom.clipped_dim % rank
+    return min(start + blk, dim) if clipped else start + blk
+
+
+def _index(geom: Any, index_map: Any, point: tuple[int, ...]
+           ) -> tuple[int, ...]:
+    """Block indices of ``index_map`` at a grid point, reading the
+    geometry's scalar-prefetch vector where it has one."""
+    prefetch = (np.asarray(geom.prefetch, np.int32),) if geom.prefetch else ()
+    return tuple(int(v) for v in index_map(*point, *prefetch))
+
+
+def _blocks(block: Sequence[int | None]) -> list[int]:
+    # a squeezed (None) dimension is one element at its index
+    return [1 if b is None else b for b in block]
 
 
 def _check_operand(
@@ -71,6 +90,7 @@ def _check_operand(
     index_map: Any,
 ) -> list[Finding]:
     out: list[Finding] = []
+    block = _blocks(block)
     if len(shape) != len(block):
         out.append(Finding(
             R_PL_OOB, FAIL,
@@ -81,7 +101,7 @@ def _check_operand(
         return out
     for point in _grid_points(geom.grid):
         try:
-            idx = tuple(int(v) for v in index_map(*point))
+            idx = _index(geom, index_map, point)
         except Exception as e:
             out.append(Finding(
                 R_PL_OOB, FAIL,
@@ -100,7 +120,7 @@ def _check_operand(
             return out
         for d, (i, blk, dim) in enumerate(zip(idx, block, shape)):
             start = i * blk
-            stop = _extent(geom, d, start, blk, dim)
+            stop = _extent(geom, d, len(shape), start, blk, dim)
             if i < 0 or start >= dim or stop > dim:
                 out.append(Finding(
                     R_PL_OOB, FAIL,
@@ -153,12 +173,13 @@ def check_pallas_out_alias(geom: Any) -> list[Finding]:
     seen: dict[tuple[int, ...], tuple[int, ...]] = {}
     for point in _grid_points(geom.grid):
         try:
-            idx = tuple(int(v) for v in geom.out_index_map(*point))
+            idx = _index(geom, geom.out_index_map, point)
         except Exception:
             return out  # crash is the oob rule's finding, not an alias
-        box = [(i * blk, _extent(geom, d, i * blk, blk, dim))
+        rank = len(geom.out_shape)
+        box = [(i * blk, _extent(geom, d, rank, i * blk, blk, dim))
                for d, (i, blk, dim) in enumerate(
-                   zip(idx, geom.out_block, geom.out_shape))]
+                   zip(idx, _blocks(geom.out_block), geom.out_shape))]
         if any(a >= b for a, b in box):
             continue
         if idx in seen:
@@ -387,6 +408,28 @@ GEOMETRY_SHAPES: tuple[tuple[int, int, int, int], ...] = (
     (2, 8, 33554432, 32768),
 )
 
+# (r, k, sources, b, block_b) of operand-reference products swept by
+# default: the cells' products as the repair program hands them to the
+# kernel, each source a (shape, leading indices) pair at the last stripe
+# and node it can read, tiled as ops.gf_product does for the rows a
+# step moves.  DRC(9,6,3) Decode and RelayerEncode at 3 stripes (64 MiB
+# blocks) and Decode on a 1 MiB strip; RS(9,6,3) Decode, each helper's
+# one-row blocks read as its (S, B) slab, and MSR(9,6,3) Decode;
+# DRC(8,6,4) Decode on a chip of four, its cross units received.
+_DRC, _RS, _MSR = (3, 9, 3, 22369664), (9, 3, 67108864), (3, 9, 27, 2485632)
+OPERAND_SHAPES: tuple[tuple[int, int, tuple[Any, ...], int, int], ...] = (
+    (3, 12, ((_DRC, (2, 2)), (_DRC, (2, 1)), ((3, 22369664), ()),
+             ((3, 22369664), ())), 22369664, 32768),
+    (3, 5, ((_DRC, (2, 8)), ((3, 22369664), ()), ((3, 22369664), ())),
+     22369664, 32768),
+    (3, 12, (((1, 9, 3, 349568), (0, 1)), ((1, 9, 3, 349568), (0, 2)),
+             ((3, 349568), ()), ((3, 349568), ())), 349568, 32768),
+    (1, 6, tuple((_RS, (i,)) for i in range(3, 9)), 67108864, 16384),
+    (27, 72, tuple((_MSR, (2, i)) for i in range(1, 9)), 2485632, 4096),
+    (2, 8, (((2, 2, 2, 33554432), (1, 1)), ((2, 33554432), ()),
+            ((2, 33554432), ()), ((2, 33554432), ())), 33554432, 32768),
+)
+
 _KERNEL_MODULES = ("repro.kernels.gf_matmul", "repro.kernels.ops")
 
 
@@ -507,7 +550,8 @@ def pallas_mutation_findings(
 
 __all__ = [
     "R_PL_OOB", "R_PL_ALIAS", "R_PL_DTYPE", "PALLAS_MUTATIONS",
-    "GEOMETRY_SHAPES", "kernel_source_paths", "analyze_geometry",
+    "GEOMETRY_SHAPES", "OPERAND_SHAPES", "kernel_source_paths",
+    "analyze_geometry",
     "check_gf_dtype", "verify_kernel_geometry", "verify_kernel_source",
     "mutate_pallas", "pallas_mutation_findings",
 ]

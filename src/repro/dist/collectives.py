@@ -14,20 +14,23 @@ stripe on (1, 1).  The lowering is two-phase:
   unit pool, and integer gather schedules for the cross-pod ship and
   the target decode.  Pure numpy; no devices needed, which is what the
   ``spmd.cross_bytes`` verifier rule exploits.
-* :func:`make_spmd_repair` turns a spec into a ``shard_map`` body:
+* :func:`make_spmd_repair` turns a spec into a ``shard_map`` body that
+  wires the repair as units, rows of arrays that each GF product reads
+  where they lie (no XLA op builds a kernel's operand; an encode slot
+  that only selects rows runs no product):
 
-  - **inner** — NodeEncode then the rack's pool, assembled from local
-    rows or by ``all_gather`` over the ``node`` axis *only* (twice when
-    relayers exist: node units, then relayer units), so intra-rack
-    aggregation never crosses a pod boundary;
-  - **cross** — one ``lax.ppermute`` over ``pod`` per source rack,
-    statically sliced to exactly that rack's cross units, so the
-    compiled HLO's collective-permute bytes equal
+  - **inner** — NodeEncode then the rack's pool, the slots' units where
+    the rack shares a device, else an ``all_gather`` over the ``node``
+    axis *only* (twice when relayers exist: node units, then relayer
+    units), so intra-rack aggregation never crosses a pod boundary;
+  - **cross** — one ``lax.ppermute`` over ``pod`` per source rack, of
+    exactly that rack's cross units, so the compiled HLO's
+    collective-permute bytes equal
     ``plan.traffic_blocks()["cross_rack_blocks"] * alpha * sub`` — the
     Eq. (3) bound as a property of the *collective schedule*, not just
-    the plan.  Where all racks share one device it is a local ``take``
-    of the same pool rows;
-  - **decode** — the collector (output row ``target_pod * w``) gathers
+    the plan.  Where all racks share one device the target reads the
+    same pool rows where they lie;
+  - **decode** — the collector (output row ``target_pod * w``) reads
     its canonical unit order and applies the decode matrix.
 
   Each stage runs under a ``jax.named_scope`` named from
@@ -47,7 +50,7 @@ cross-check against the plan's symbolic accounting.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
+from typing import Any, Callable, Optional
 
 import numpy as np
 
@@ -295,160 +298,305 @@ def _node_ids(spec: SpmdRepairSpec, pods: int, nodes: int
              for b in range(per_rack)] for a in range(racks)]
 
 
-def _runs_product(mats: np.ndarray, held: list[int]) -> bool:
-    # a slot whose nodes have nothing to send, on every device, computes
-    # nothing
-    return bool(mats[held].any())
+# A unit of the repair, as the program's GF products read it: row
+# ``row`` of a source, or None, a zero row.
+Unit = Optional[tuple["_Source", int]]
 
 
-def _stage_matrices(spec: SpmdRepairSpec, pods: int, nodes: int
-                    ) -> dict[str, list[np.ndarray]]:
-    """For each GF product one device's repair program runs for the
-    stripe, by stage, the matrices it may apply: one per device of its
-    slot, on a ``(pods, nodes)`` mesh."""
-    slots = [held for rack in _node_ids(spec, pods, nodes) for held in rack]
-    out = {"node_encode": [spec.node_mats[held] for held in slots
-                           if _runs_product(spec.node_mats, held)]}
-    if spec.ru:
-        out["relayer_encode"] = [spec.relayer_mats[held] for held in slots
-                                 if _runs_product(spec.relayer_mats, held)]
-    out["decode"] = [spec.decode[None]]
-    return out
+@dataclasses.dataclass(eq=False)  # one source is one array: by identity
+class _Source:
+    """An array whose rows the repair's GF products read where they lie:
+    the ``(rows, B)`` slab ``array[lead]``.  ``array`` is None where the
+    wiring only counts."""
+
+    array: Any
+    lead: tuple[int, ...] = ()
 
 
 def _is_selection(mats: np.ndarray) -> bool:
-    # a 0/1 row selection: every row copies at most one input row
-    # unchanged, so the product is a row take
-    return bool((mats <= 1).all()
-                and (np.count_nonzero(mats, axis=-1) <= 1).all())
+    # every matrix of the slot that sends anything is one 0/1 row
+    # selection: each row copies at most one input row unchanged, so the
+    # slot's units are rows of its input, read in place.  A device whose
+    # matrix is zero sends nothing, so nothing reads its units there.
+    sends = mats[mats.any(axis=(1, 2))]
+    return bool(len(sends) and (sends == sends[0]).all()
+                and (sends[0] <= 1).all()
+                and (np.count_nonzero(sends[0], axis=-1) <= 1).all())
 
 
-def make_spmd_repair(spec: SpmdRepairSpec, path: str) -> Callable[[Any], Any]:
+class _Wiring:
+    """One device's repair as operand references: which units each GF
+    product reads, where they lie, and which slots need no product.
+
+    This class only counts, by stage, the products it would run, the
+    encode slots that are selections, and the operand rows the products
+    read (:class:`GfCounts`); :class:`_Program` runs the same wiring on
+    arrays, so the counts are the program's by construction."""
+
+    def __init__(self) -> None:
+        self.products: dict[str, int] = {}
+        self.selections: dict[str, int] = {}
+        self.rows: dict[str, int] = {}
+
+    def encode(self, stage: str, mats: np.ndarray, units: list[Unit]
+               ) -> list[Unit]:
+        """A NodeEncode or RelayerEncode slot, ``mats`` its matrices one a
+        device: zero rows where no device's node sends, the selected input
+        units where :func:`_is_selection`, else the product's rows."""
+        self._stage(stage)
+        if not mats.any():
+            return [None] * mats.shape[1]
+        if _is_selection(mats):
+            self.selections[stage] += 1
+            sel = mats[mats.any(axis=(1, 2))][0]
+            return self.select([units[int(row.argmax())] if row.any()
+                                else None for row in sel])
+        out = self.product(stage, mats, units)
+        return [None if out is None else (out, i)
+                for i in range(mats.shape[1])]
+
+    def select(self, units: list[Unit]) -> list[Unit]:
+        """A selection's units: its input's, read where they lie."""
+        return units
+
+    def product(self, stage: str, mats: np.ndarray, units: list[Unit]
+                ) -> _Source | None:
+        """The product of ``mats`` (one matrix a device) over ``units``,
+        its columns' inputs, or None where it reads nothing: zero units,
+        and columns no device's matrix uses, are dropped, and each
+        source's picked rows form one operand reference, its columns
+        taken in that order."""
+        self._stage(stage)
+        used = mats.any(axis=(0, 1))
+        picks: dict[_Source, list[tuple[int, int]]] = {}  # (column, row)
+        for c, unit in enumerate(units):
+            if unit is not None and used[c]:
+                picks.setdefault(unit[0], []).append((c, unit[1]))
+        cols = [c for pairs in picks.values() for c, _ in pairs]
+        if not cols:
+            return None
+        self.products[stage] += 1
+        self.rows[stage] += len(cols)
+        return self.apply(mats[..., cols], [
+            (src, tuple(row for _, row in pairs))
+            for src, pairs in picks.items()])
+
+    def _stage(self, stage: str) -> None:
+        for counts in (self.products, self.selections, self.rows):
+            counts.setdefault(stage, 0)
+
+    def apply(self, mats: np.ndarray,
+              refs: list[tuple[_Source, tuple[int, ...]]]) -> _Source:
+        return _Source(None)
+
+    def all_gather(self, units: list[Unit]) -> _Source:
+        return _Source(None)
+
+    def ship(self, units: list[Unit], q: int) -> _Source:
+        return _Source(None)
+
+
+def _node_units(alpha: int, x: Any, lead: tuple[int, ...], nodes: int
+                ) -> list[list[Unit]]:
+    """Each of a device's ``nodes`` local nodes' alpha units in the
+    resident block ``x[lead]``, ``(nodes, alpha, B)`` (x None: counting).
+
+    A node's alpha rows are the block a kernel moves a step.  Where alpha
+    is 1 that block is one row, which the chip's uint8 tiling pads to
+    four, so XLA lays the block out with another axis second-minor: the
+    stripes of an ``(S, nodes, 1, B)`` stack, read as node i's ``(S, B)``
+    slab (a bitcast of that layout, where a slab of a stripe's nodes
+    would be a relayout copy of the stack), and the nodes of one
+    stripe's ``(nodes, 1, B)``, read as its ``(nodes, B)`` slab."""
+    if alpha == 1:
+        flat = None if x is None else x.reshape(*x.shape[:-2], x.shape[-1])
+        if lead:
+            by_node = None if flat is None else flat.transpose(1, 0, 2)
+            return [[(_Source(by_node, (i,)), lead[0])]
+                    for i in range(nodes)]
+        slab = _Source(flat)
+        return [[(slab, i)] for i in range(nodes)]
+    srcs = [_Source(x, (*lead, i)) for i in range(nodes)]
+    return [[(src, c) for c in range(alpha)] for src in srcs]
+
+
+def _wire(spec: SpmdRepairSpec, racks: int, per_rack: int,
+          held: list[list[list[int]]], eng: _Wiring, xs: list[list[Unit]]
+          ) -> _Source:
+    """One stripe's repair on one device, through ``eng``; returns the
+    Decode product.  ``xs[i]`` are the device's local node i's units
+    (:func:`_node_units`), ``held`` :func:`_node_ids` of the mesh.
+
+    NodeEncode, then the rack's pool: the slots' units where the rack's
+    nodes share the device, else an ``all_gather`` over ``node`` only,
+    so aggregation never crosses a pod; RelayerEncode over [own ++
+    pool], its units pooled in-rack too (rows w*nu .. w*nu + w*ru).  The
+    cross hop ships each source rack's scheduled units to the target
+    rack, one ``ppermute`` over ``pod`` per source pod where racks are
+    devices, so compiled cross-pod bytes == sum(len(rows)) * sub; where
+    racks share the device the target reads them where they lie.
+    Decode reads the canonical unit order."""
+    w = spec.w
+
+    def own(a: int, b: int) -> list[Unit]:
+        return xs[a * per_rack + b]
+
+    def rack_pool(slots: list[list[Unit]]) -> list[Unit]:
+        if per_rack == w:
+            return [u for slot in slots for u in slot]
+        with stage_scope("inner"):
+            src = eng.all_gather(slots[0])
+        return [(src, i) for i in range(w * len(slots[0]))]
+
+    pools = []
+    for a in range(racks):
+        with stage_scope("node_encode"):
+            ys = [eng.encode("node_encode", spec.node_mats[held[a][b]],
+                             own(a, b)) for b in range(per_rack)]
+        pool = rack_pool(ys)
+        if spec.ru:
+            with stage_scope("relayer_encode"):
+                zs = [eng.encode("relayer_encode",
+                                 spec.relayer_mats[held[a][b]],
+                                 own(a, b) + pool) for b in range(per_rack)]
+            pool = pool + rack_pool(zs)
+        pools.append(pool)
+
+    # declared schedule; plan_to_spmd never emits a (q, q) self-send and
+    # the lowered verifier rule lowered.spmd.permute-partial proves it
+    cross = [(q, rows) for q, dst, rows in spec.permute_steps() if q != dst]
+    if racks == 1:
+        pool = pools[0]
+        recvs = []
+        for q, rows in cross:
+            with stage_scope("cross"):
+                src = eng.ship([pool[i] for i in rows], q)
+            recvs.append([(src, i) for i in range(len(rows))])
+    else:
+        pool = pools[spec.target_pod]
+        recvs = [[pools[q][i] for i in rows] for q, rows in cross]
+    pool = pool + [u for recv in recvs for u in recv]
+    with stage_scope("decode"):
+        rec = eng.product("decode", spec.decode[None],
+                          [pool[i] for i in spec.target_idx])
+    if rec is None:
+        raise ValueError(f"{spec.family}: Decode reads no unit")
+    return rec
+
+
+class _Program(_Wiring):
+    """The wiring on one device's arrays: ``path`` (:func:`gf_path`)
+    names the GF product, ``pos`` is the device's mesh position, which
+    picks its matrix where a slot's nodes are one a device."""
+
+    def __init__(self, path: str, pos: Any, target_pod: int, width: int
+                 ) -> None:
+        super().__init__()
+        self.path, self.pos = path, pos
+        self.target_pod, self.width = target_pod, width
+
+    def apply(self, mats: np.ndarray,
+              refs: list[tuple[_Source, tuple[int, ...]]]) -> _Source:
+        import jax
+        import jax.numpy as jnp
+
+        # on pallas each matrix is bit-expanded here, on the host, into
+        # the kernel's masks and handed to it as data, so one compiled
+        # kernel serves every node of a shape
+        if self.path == "pallas":
+            from repro.kernels import ops
+
+            coeffs = jnp.asarray(ops.bit_expand(mats))
+        else:
+            coeffs = jnp.asarray(mats)
+        m = coeffs[0] if len(mats) == 1 else jax.lax.dynamic_index_in_dim(
+            coeffs, self.pos, 0, keepdims=False)
+        if self.path == "pallas":
+            return _Source(ops.gf_product(m, [
+                ops.Operand(src.array, src.lead, rows) for src, rows in refs]))
+        from repro.core.gf_jax import gf_matmul_jnp
+
+        return _Source(gf_matmul_jnp(m, self.gather(
+            [(src, i) for src, rows in refs for i in rows])))
+
+    def select(self, units: list[Unit]) -> list[Unit]:
+        # gf_matmul_jnp reads gathered buffers, not references: there the
+        # selection reads its rows, as slices, in its own stage
+        if self.path == "pallas":
+            return units
+        return [None if u is None else (_Source(
+            u[0].array[(*u[0].lead, slice(u[1], u[1] + 1))]), 0)
+            for u in units]
+
+    def gather(self, units: list[Unit]) -> Any:
+        """The units as one (len(units), B) array: the program's one
+        concatenation, for ``gf_matmul_jnp`` and the collectives, which
+        take buffers."""
+        import jax.numpy as jnp
+
+        return jnp.concatenate([
+            jnp.zeros((1, self.width), jnp.uint8) if u is None
+            else u[0].array[(*u[0].lead, slice(u[1], u[1] + 1))]
+            for u in units], axis=0)
+
+    def all_gather(self, units: list[Unit]) -> _Source:
+        import jax
+
+        return _Source(jax.lax.all_gather(self.gather(units), "node",
+                                          tiled=True))
+
+    def ship(self, units: list[Unit], q: int) -> _Source:
+        import jax
+
+        src = units[0][0] if units[0] is not None else None
+        whole = (src is not None and not src.lead
+                 and src.array.shape[0] == len(units)
+                 and units == [(src, i) for i in range(len(units))])
+        buf = src.array if whole else self.gather(units)
+        return _Source(jax.lax.ppermute(buf, "pod", [(q, self.target_pod)]))
+
+
+def make_spmd_repair(spec: SpmdRepairSpec, path: str) -> Callable[..., Any]:
     """Build the shard_map body over a ``("pod", "node")`` mesh.
 
-    Each device holds ``(racks * nodes, alpha, sub)`` of the node-major
-    stripe, as :func:`mesh_layout` reads off the mesh's axis sizes.
-    Where a rack's nodes share a device its pool is assembled locally,
-    else by ``all_gather`` over ``node``.  Where racks share a device
-    the cross stage is a local ``take`` of the same pool rows the
-    ``ppermute`` over ``pod`` would ship.  Output row ``target_pod * w``
-    carries the reconstructed payload; every other row is zero.
+    The body takes a device's ``(racks * nodes, alpha, sub)`` block of
+    the node-major stripe, as :func:`mesh_layout` reads off the mesh's
+    axis sizes, or with ``s`` its ``(S, racks * nodes, alpha, sub)``
+    block of S stripes and the stripe to repair.  It runs :func:`_wire`:
+    each GF product reads its operand rows where they lie, in the block,
+    in another product's output or in a buffer the cross hop received,
+    and an encode slot that only selects rows runs none.  Output row
+    ``target_pod * w`` carries the reconstructed payload; every other
+    row is zero.
 
-    ``path`` (:func:`gf_path` of the mesh) names the GF product: on
-    ``pallas`` each coefficient matrix is bit-expanded here, on the
-    host, into the kernel's masks and handed to it as data, so one
-    compiled kernel serves every node of a shape; on ``jnp`` the
-    matrices go to ``gf_matmul_jnp`` as they are.
+    ``path`` (:func:`gf_path` of the mesh) names the GF product: the
+    Pallas kernel, reading its operand references in place, on
+    ``pallas``; on ``jnp`` ``gf_matmul_jnp`` over the rows gathered.
     """
     import jax
     import jax.numpy as jnp
 
-    product: Callable[[Any, Any], Any]
-    if path == "pallas":
-        from repro.kernels import ops
-
-        product = ops.gf_product
-
-        def coeffs(m: np.ndarray) -> Any:
-            return jnp.asarray(ops.bit_expand(m))
-    elif path == "jnp":
-        from repro.core.gf_jax import gf_matmul_jnp
-
-        product = gf_matmul_jnp
-
-        def coeffs(m: np.ndarray) -> Any:
-            return jnp.asarray(m)
-    else:
+    if path not in ("pallas", "jnp"):
         raise ValueError(f"GF path {path!r} is neither 'pallas' nor 'jnp'")
+    collector = spec.target_pod * spec.w
 
-    w, nu, ru = spec.w, spec.nu, spec.ru
-    # declared schedule; plan_to_spmd never emits a (q, q) self-send and
-    # the lowered verifier rule lowered.spmd.permute-partial proves it
-    cross = [(q, rows) for q, dst, rows in spec.permute_steps() if q != dst]
-    collector = spec.target_pod * w
-
-    def take(pool: Any, rows: tuple[int, ...]) -> Any:
-        # static row picks as slices: a gather of payload-wide rows is
-        # split into thousands of pieces by the TPU compiler
-        return jnp.concatenate([pool[i:i + 1] for i in rows], axis=0)
-
-    def encode(mats: np.ndarray, held: list[int], pos: Any,
-               operand: Any) -> Any:
-        # the slot ``held`` these nodes, one a device; this device, at
-        # mesh position pos, encodes as the node it holds
-        if not _runs_product(mats, held):
-            return jnp.zeros_like(operand, shape=(mats.shape[1],
-                                                  operand.shape[1]))
-        if len(held) == 1:
-            return product(coeffs(mats[held[0]]), operand)
-        m = jax.lax.dynamic_index_in_dim(coeffs(mats[held]), pos, 0,
-                                         keepdims=False)
-        return product(m, operand)
-
-    def repair(x: Any) -> Any:
+    def repair(x: Any, s: int | None = None) -> Any:
         pods = jax.lax.axis_size("pod")
         nodes = jax.lax.axis_size("node")
         racks, per_rack = mesh_layout(spec, pods, nodes)
         p = jax.lax.axis_index("pod") if pods > 1 else 0
         j = jax.lax.axis_index("node") if nodes > 1 else 0
-
-        def own(a: int, b: int) -> Any:
-            # node b of local rack a; row indexing, as a reshape of the
-            # uint8 block compiles in time that grows with its size
-            return x[a * per_rack + b]
-
-        def rack_pool(units: list[Any]) -> Any:
-            # a rack's units, node-major: local rows or all_gather over
-            # the node axis only, so aggregation never crosses a pod
-            with stage_scope("inner"):
-                if per_rack == w:
-                    return jnp.concatenate(units, axis=0)
-                return jax.lax.all_gather(units[0], "node", tiled=True)
-
-        # inner: NodeEncode, then RelayerEncode over [own ++ rack pool];
-        # relayer units are pooled in-rack too (rows w*nu .. w*nu + w*ru)
         pos = p * nodes + j  # this device's mesh position
         held = _node_ids(spec, pods, nodes)
+        eng = _Program(path, pos, spec.target_pod, x.shape[-1])
+        xs = _node_units(spec.alpha, x, () if s is None else (s,),
+                         racks * per_rack)
+        rec = _wire(spec, racks, per_rack, held, eng, xs).array
         # each slot's node on this device: a Python int where one device
-        # holds every rack
+        # holds every rack; only the collector's row keeps the rebuilt
+        # block
         ids = [[h[0] if len(h) == 1 else jnp.asarray(h)[pos] for h in rack]
                for rack in held]
-        pools = []
-        for a in range(racks):
-            with stage_scope("node_encode"):
-                ys = [encode(spec.node_mats, held[a][b], pos, own(a, b))
-                      for b in range(per_rack)]
-            pool = rack_pool(ys)
-            if ru:
-                with stage_scope("relayer_encode"):
-                    zs = [encode(spec.relayer_mats, held[a][b], pos,
-                                 jnp.concatenate([own(a, b), pool], axis=0))
-                          for b in range(per_rack)]
-                relayed = rack_pool(zs)
-                with stage_scope("inner"):
-                    pool = jnp.concatenate([pool, relayed], axis=0)
-            pools.append(pool)
-
-        # cross: each source rack ships exactly its scheduled units to
-        # the target rack — one collective-permute per source pod when
-        # racks are devices, so compiled cross-pod bytes ==
-        # sum(len(rows)) * sub; a local take when they share one
-        with stage_scope("cross"):
-            if racks == 1:
-                pool = pools[0]
-                recvs = [
-                    jax.lax.ppermute(take(pool, rows), "pod",
-                                     [(q, spec.target_pod)])
-                    for q, rows in cross
-                ]
-            else:
-                pool = pools[spec.target_pod]
-                recvs = [take(pools[q], rows) for q, rows in cross]
-            pool2 = jnp.concatenate([pool, *recvs], axis=0) if recvs else pool
-
-        # decode; only the collector's row keeps it
-        with stage_scope("decode"):
-            rec = product(coeffs(spec.decode), take(pool2, spec.target_idx))
         with stage_scope("write"):
             zero = jnp.zeros_like(rec)
             return jnp.stack([
@@ -484,38 +632,45 @@ def _record_schedule(spec: SpmdRepairSpec, sub_bytes: int) -> None:
 @dataclasses.dataclass(frozen=True)
 class GfCounts:
     """What one call's GF products do on one device, summed over the
-    call's stripes: by stage, the products :func:`make_spmd_repair`
-    emits, as it emits them, and the selections among them, whose every
-    matrix, on every device of the slot, only copies sub-blocks (as an
-    optimal-access helper's send does); and the sub-blocks the helpers'
-    NodeEncode matrices read, their nonzero columns."""
+    call's stripes, by stage: the products :func:`make_spmd_repair`
+    runs; the encode slots that run none, as every matrix of the slot
+    that sends anything only copies sub-blocks (as an optimal-access
+    helper's send does), so their consumers read those rows in place;
+    and the operand rows the products read, each where it lies.  Also
+    the sub-blocks the helpers' NodeEncode matrices read, their nonzero
+    columns."""
 
     products: dict[str, int]
     selections: dict[str, int]
+    rows_in_place: dict[str, int]
     sub_blocks_read: int
 
     @classmethod
     def of(cls, specs: list[SpmdRepairSpec], pods: int, nodes: int
            ) -> "GfCounts":
-        products: dict[str, int] = {}
-        selections: dict[str, int] = {}
+        eng = _Wiring()
         for spec in specs:
-            for stage, mats in _stage_matrices(spec, pods, nodes).items():
-                products[stage] = products.get(stage, 0) + len(mats)
-                selections[stage] = (selections.get(stage, 0)
-                                     + sum(map(_is_selection, mats)))
-        return cls(products, selections, sum(
+            racks, per_rack = mesh_layout(spec, pods, nodes)
+            xs = _node_units(spec.alpha, None, (), racks * per_rack)
+            _wire(spec, racks, per_rack, _node_ids(spec, pods, nodes), eng,
+                  xs)
+        return cls(eng.products, eng.selections, eng.rows, sum(
             int(np.count_nonzero(spec.node_mats.any(axis=1)))
             for spec in specs))
 
 
 def _record_gf(path: str, counts: GfCounts) -> None:
-    """Book the call's GF products that run as the Pallas kernel (none
-    on the ``jnp`` path) and those that are selections, by stage, and the
+    """Book, by stage, the call's GF products that run as the Pallas
+    kernel and the operand rows they read in place (none of either on
+    the ``jnp`` path), and the encode slots that are selections; and the
     sub-blocks NodeEncode reads."""
+    kernel = path == "pallas"
     for stage, count in counts.products.items():
-        obs.counter_add("repair.gf_kernel_calls",
-                        count if path == "pallas" else 0, stage=stage)
+        obs.counter_add("repair.gf_kernel_calls", count if kernel else 0,
+                        stage=stage)
+        obs.counter_add("repair.gf_rows_in_place",
+                        counts.rows_in_place[stage] if kernel else 0,
+                        stage=stage)
         obs.counter_add("repair.gf_selection_calls",
                         counts.selections[stage], stage=stage)
     obs.counter_add("repair.helper_sub_blocks_read", counts.sub_blocks_read)
@@ -584,13 +739,7 @@ def _recovery_program(
         counts = GfCounts.of(specs, *mesh.devices.shape)
 
         def body(x: Any) -> Any:  # (S, racks*nodes, alpha, sub) per device
-            # a stripe's rows are NodeEncode's input: its split from the
-            # stack copies the blocks on the TPU, so it is scoped with it
-            outs = []
-            for s, fn in enumerate(bodies):
-                with stage_scope("node_encode"):
-                    stripe = x[s]
-                outs.append(fn(stripe))
+            outs = [fn(x, s) for s, fn in enumerate(bodies)]
             with stage_scope("write"):
                 return jnp.stack(outs, axis=0)
 
@@ -632,10 +781,11 @@ def spmd_node_recovery(
     The root span carries ``gf_path`` (:func:`gf_path`), ``alpha`` and
     ``sub_bytes``.  By stage, the counter ``repair.gf_kernel_calls``
     counts the GF products of the call that run as the Pallas kernel,
-    and ``repair.gf_selection_calls`` those that only select sub-blocks;
-    ``repair.helper_sub_blocks_read`` counts the sub-blocks their
-    NodeEncode reads (:class:`GfCounts`, worked out when the program is
-    built).
+    ``repair.gf_rows_in_place`` the operand rows they read where they
+    lie, and ``repair.gf_selection_calls`` the encode slots that only
+    select sub-blocks and run none; ``repair.helper_sub_blocks_read``
+    counts the sub-blocks the helpers' NodeEncode reads
+    (:class:`GfCounts`, worked out when the program is built).
     """
     n_stripes = int(payloads.shape[0])
     sub_bytes = int(payloads.shape[-1])

@@ -13,40 +13,49 @@ verifier and the chip smoke run call it).  It
   (chosen by shape, on every backend).
 
 :func:`gf_product` is the same kernel as the SPMD repair program calls
-it on a TPU mesh, with masks it built when the program was built.
+it on a TPU mesh, with masks it built when the program was built and
+operand references to the rows where they lie.
 """
 from __future__ import annotations
+
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from .gf_matmul import WORD_TILE, bit_expand, gf_matmul_pallas
+from .gf_matmul import WORD_TILE, Operand, bit_expand, gf_matmul_pallas
 from .ref import gf_matmul_ref
 
 _LANE = 128
 # Payload bytes in and out of one grid step, and the widest tile: the
 # grid costs a fixed time a step, and wider tiles stop paying (chip
-# sweep, PERF.md).
+# sweep, PERF.md).  The narrowest is one vreg of 32-bit words a row (8
+# sublanes of 128 lanes): below it each row's words fill part of a vreg
+# and the VPU does the same work for fewer bytes.
 STEP_BYTES = 512 * 1024
 MAX_TILE = 65536
+MIN_TILE = 8 * WORD_TILE
 
 
-def gf_product(masks: jax.Array, x: jax.Array) -> jax.Array:
-    """(R, 8K) coefficient masks (a constant or traced) times a (K, B)
-    uint8 payload on the TPU: the compiled kernel, tiled by
-    :func:`choose_block_b`."""
+def gf_product(masks: jax.Array, operands: Sequence[Operand]) -> jax.Array:
+    """(R, 8K) coefficient masks (a constant or traced) times the K rows
+    the :class:`Operand` references pick, read where they lie, on the
+    TPU: the compiled kernel, tiled by :func:`choose_block_b` for the
+    rows it moves a step, every source's whole block."""
+    moved = sum(op.src.shape[-2] for op in operands)
     return gf_matmul_pallas(
-        masks, x, block_b=choose_block_b(masks.shape[1] // 8, masks.shape[0]))
+        masks, operands, block_b=choose_block_b(moved, masks.shape[0]))
 
 
 def choose_block_b(k: int, r: int) -> int:
-    """The payload tile of a (R, K) product: the power of two of bytes,
-    from ``WORD_TILE`` to ``MAX_TILE``, whose K input and R output rows
-    hold at most ``STEP_BYTES``."""
+    """The payload tile of a product that moves K input and R output rows
+    a step: the power of two of bytes, from ``MIN_TILE`` to
+    ``MAX_TILE``, whose rows hold at most ``STEP_BYTES``, else
+    ``MIN_TILE``."""
     tb = MAX_TILE
-    while tb > WORD_TILE and tb * (k + r) > STEP_BYTES:
+    while tb > MIN_TILE and tb * (k + r) > STEP_BYTES:
         tb //= 2
     return tb
 

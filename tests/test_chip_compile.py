@@ -13,7 +13,8 @@ runs it:
 * DRC(8,6,4) node recovery with one rack per chip on four chips, whose
   compiled cross-pod permute bytes must equal the plan's Eq. (3) count;
 * the four recovery programs of the benchmark, each GF product of
-  which compiles to the Pallas kernel under its stage's scope.
+  which compiles to the Pallas kernel under its stage's scope, reading
+  its operand rows where they lie.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -25,6 +26,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
@@ -135,26 +137,54 @@ def test_four_chip_drc_cross_pod_bytes_equal_eq3(topo):
 
 
 _PAD = re.compile(r"= \S+ pad\(.*padding=([0-9_x-]+)")
+# one HLO instruction: its name, result shape (a tuple of shapes for an
+# async op) and opcode, the first lower-case word before "(" after " = "
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z0-9_-]*)\(")
+_SHAPE = re.compile(r"^(\w+)\[([0-9,]*)\]")
+_GF_STAGES = ("node_encode", "relayer_encode", "decode")
+_PLUMBING = ("bitcast", "tuple", "get-tuple-element")
+
+
+def _entry(text: str) -> list[str]:
+    lines = text.splitlines()
+    at = next(i for i, line in enumerate(lines) if line.startswith("ENTRY"))
+    return lines[at + 1:lines.index("}", at)]
+
+
+def _payload_bytes(shape: str) -> int:
+    m = _SHAPE.match(shape)
+    if m is None or m.group(1) != "u8":
+        return 0
+    return int(np.prod([int(d) for d in m.group(2).split(",") if d]))
 
 
 @pytest.mark.parametrize("family, n, k, r, stripes, chips, kernels", [
-    ("DRC", 9, 6, 3, 3, 1, 27),
-    ("RS", 9, 6, 3, 3, 1, 21),
-    ("DRC", 8, 6, 4, 2, 4, 7),
-    ("MSR", 9, 6, 3, 3, 1, 27),
+    ("DRC", 9, 6, 3, 3, 1, 21),
+    ("RS", 9, 6, 3, 3, 1, 3),
+    ("DRC", 8, 6, 4, 2, 4, 6),
+    ("MSR", 9, 6, 3, 3, 1, 3),
 ], ids=["drc963_one_chip", "rs963_one_chip", "drc864_four_chips",
         "msr963_one_chip"])
 def test_recovery_gf_products_compile_to_the_kernel(
         topo, family, n, k, r, stripes, chips, kernels):
     """On a TPU mesh every GF product of NodeEncode, RelayerEncode and
     Decode is one ``tpu_custom_call`` whose ``op_name`` carries its stage
-    (what the stage metrics read), as many as ``GfCounts`` counts; no
-    pad copies a payload-wide array; the program fits HBM; and on four
-    chips the cross-pod bytes still equal Eq. (3).  DRC(8,6,4) holds 7:
+    (what the stage metrics read), as many as ``GfCounts`` counts, and
+    the kernels read their operand rows where they lie: under those
+    stages no instruction but the kernel, bitcasts, tuples and their
+    elements, and the int32 masks' and indices' small copies, and in the
+    whole program at most one payload-wide copy of the stripes (a
+    relayout for the kernel); no pad copies a payload-wide array; the
+    program fits HBM; and on four chips the cross-pod bytes still equal
+    Eq. (3).  An encode slot whose every sending matrix is one 0/1
+    selection runs no kernel.  DRC(9,6,3) holds 21: per stripe 4
+    NodeEncode (nodes 1 and 2 send raw), 2 RelayerEncode and 1 Decode.
+    RS(9,6,3) and MSR(9,6,3) (alpha 27, sub-blocks of 2,485,632 B) hold
+    3, one Decode a stripe: every helper sends raw.  DRC(8,6,4) holds 6:
     per chip and stripe 2 NodeEncode, 1 RelayerEncode and 1 Decode, less
-    one NodeEncode slot whose nodes send nothing on any chip.  MSR(9,6,3)
-    (alpha 27, sub-blocks of 2,485,632 B) holds 27: per stripe 8 NodeEncode
-    of 9 x 27 and one 27 x 72 Decode.  On one chip no collective runs."""
+    a NodeEncode slot whose nodes send nothing on any chip in each
+    stripe and one whose senders all send raw in the second.  On one
+    chip no collective runs."""
     from repro.dist.collectives import (
         GfCounts, expected_cross_units, gf_path, node_recovery_program)
     from repro.launch.hlo_analysis import cross_pod_permute_bytes
@@ -168,15 +198,33 @@ def test_recovery_gf_products_compile_to_the_kernel(
                              sharding=NamedSharding(mesh, P(None, ("pod", "node"))))
     compiled = prog.lower(x).compile()
     text = compiled.as_text()
-    want = GfCounts.of(specs, *mesh.devices.shape).products
+    want = {stage: count for stage, count in
+            GfCounts.of(specs, *mesh.devices.shape).products.items() if count}
     got: dict[str, int] = {}
     for line in text.splitlines():
-        if 'custom_call_target="tpu_custom_call"' not in line:
+        instr = _INSTR.match(line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if instr is None or op_name is None:
             continue
-        op_name = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
-        stage = next(p for p in op_name if p in want)
-        got[stage] = got.get(stage, 0) + 1
+        stage = next((p for p in op_name.group(1).split("/")
+                      if p in _GF_STAGES), None)
+        if 'custom_call_target="tpu_custom_call"' in line:
+            got[stage] = got.get(stage, 0) + 1
+        elif stage is not None and instr.group(3) not in _PLUMBING:
+            # the masks picked by a device's position and the kernels'
+            # leading indices: int32 data, none of it payload
+            assert instr.group(2).startswith(("s32", "(s32")), line
+            assert _payload_bytes(instr.group(2)) == 0, line
     assert got == want and sum(got.values()) == kernels
+    entry = _entry(text)
+    param = next(_INSTR.match(line).group(1) for line in entry
+                 if " parameter(0)" in line)
+    copies = [line for line in entry
+              if (instr := _INSTR.match(line)) is not None
+              and instr.group(3) not in ("custom-call", *_PLUMBING)
+              and re.search(rf"\(.*%{re.escape(param)}[,)]", line)
+              and _payload_bytes(instr.group(2)) >= sub]
+    assert len(copies) <= 1, copies
     for padding in _PAD.findall(text):
         assert padding.split("x")[-1] == "0_0", padding  # payload axis
     assert _device_bytes(compiled) < HBM_LIMIT

@@ -389,12 +389,13 @@ def test_cpu_repair_program_takes_jnp_product(devices):
 
 
 # Clay MSR(9,6,3) through node recovery at S = 3 (rotations 0-2): one
-# device's GF products a call, by stage, and the selections among them,
-# in each layout.  Every helper sends 9 of its 27 sub-blocks raw.
+# device's GF products a call, by stage, and the NodeEncode slots that
+# are selections and run none, in each layout.  Every helper sends 9 of
+# its 27 sub-blocks raw, so only Decode runs a product.
 MSR_LAYOUTS = {
-    1: ({"node_encode": 24, "decode": 3}, {"node_encode": 24, "decode": 0}),
-    3: ({"node_encode": 9, "decode": 3}, {"node_encode": 9, "decode": 0}),
-    9: ({"node_encode": 3, "decode": 3}, {"node_encode": 3, "decode": 0}),
+    1: ({"node_encode": 0, "decode": 3}, {"node_encode": 24, "decode": 0}),
+    3: ({"node_encode": 0, "decode": 3}, {"node_encode": 9, "decode": 0}),
+    9: ({"node_encode": 0, "decode": 3}, {"node_encode": 3, "decode": 0}),
 }
 
 
@@ -405,9 +406,9 @@ def test_msr_node_recovery_matches_numpy_in_every_layout(devices):
     sub-blocks on the (1, 1), (3, 1) and (3, 3) meshes: every rebuilt
     block equals the encoded payload and numpy ``RepairPlan.execute``
     byte for byte, every other output row is zero, and the call books
-    its GF products, its selections and the 216 helper sub-blocks it
-    reads (8 helpers x 9 of 27, a stripe) with ``alpha`` and
-    ``sub_bytes`` on its root span."""
+    its GF products (Decode alone), its selections and the 216 helper
+    sub-blocks it reads (8 helpers x 9 of 27, a stripe) with ``alpha``
+    and ``sub_bytes`` on its root span."""
     out = run_sub(
         f"""
         import json
@@ -457,12 +458,13 @@ def test_msr_node_recovery_matches_numpy_in_every_layout(devices):
 @pytest.mark.parametrize("family, selections, reads", [
     ("MSR", 24, 216), ("DRC", 6, 54), ("RS", 18, 18)])
 def test_node_encode_selections_and_reads_are_booked(family, selections, reads):
-    """The (9,6,3) codes on one device at S = 3: the NodeEncode products
-    that only select sub-blocks (MSR all 24, DRC the 6 of nodes 1 and 2,
-    RS all 18), none of the RelayerEncode or Decode products, and the
-    sub-blocks NodeEncode reads (MSR 9 of 27 from each of 8 helpers, DRC
-    all 3 from 6 senders, RS 1 from 6), booked when the program is built
-    and again on each call."""
+    """The (9,6,3) codes on one device at S = 3: the NodeEncode slots
+    that only select sub-blocks and run no product (MSR all 24, DRC the
+    6 of nodes 1 and 2, RS all 18), no RelayerEncode or Decode slot, and
+    the sub-blocks NodeEncode reads (MSR 9 of 27 from each of 8 helpers,
+    DRC all 3 from 6 senders, RS 1 from 6), booked when the program is
+    built and again on each call; off a TPU no operand row is read in
+    place by a kernel."""
     from repro import obs
     from repro.core.codes import make_code
     from repro.dist import collectives
@@ -489,6 +491,9 @@ def test_node_encode_selections_and_reads_are_booked(family, selections, reads):
         for stage in set(counts.products) - {"node_encode"}:
             assert tr.counter_value("repair.gf_selection_calls",
                                     stage=stage) == 0
+        for stage in counts.products:
+            assert tr.counter_value("repair.gf_rows_in_place",
+                                    stage=stage) == 0
         assert tr.counter_value("repair.helper_sub_blocks_read") == calls * reads
         for root in tr.spans_named("repair.spmd_node_recovery"):
             assert root.attrs["alpha"] == code.alpha
@@ -496,6 +501,75 @@ def test_node_encode_selections_and_reads_are_booked(family, selections, reads):
     # worked out once, in the program's cache entry
     key = (repr(code), 0, 3, mesh)
     assert collectives._RECOVERY_PROGRAMS[key][2] == counts
+
+
+# The kernel path through the program, by layout: (family, n, k, r,
+# devices), and one device's kernels a call at S = 2 with the operand
+# rows they read in place.
+KERNEL_PATH_CASES = {
+    "drc963_stripe_on_one_device": ("DRC", 9, 6, 3, 1, 14, 68),
+    "rs963_stripe_on_one_device": ("RS", 9, 6, 3, 1, 2, 12),
+    "msr963_stripe_on_one_device": ("MSR", 9, 6, 3, 1, 2, 144),
+    "drc864_rack_per_device": ("DRC", 8, 6, 4, 4, 6, 26),
+    "drc963_node_per_device": ("DRC", 9, 6, 3, 9, 6, 40),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_PATH_CASES))
+def test_kernel_path_reads_operands_in_place_and_matches_numpy(case):
+    """The repair program on the Pallas kernel (interpret mode), each
+    product reading its operand rows where they lie: in the resident
+    stripes, in another product's output, in a buffer an ``all_gather``
+    or a ``ppermute`` received.  Every rebuilt block equals numpy
+    ``RepairPlan.execute`` byte for byte, every other output row is zero,
+    and the call books its kernels and the rows they read in place as
+    ``GfCounts`` works them out."""
+    family, n, k, r, devices, kernels, rows = KERNEL_PATH_CASES[case]
+    out = run_sub(
+        f"""
+        import functools, json
+        import jax, jax.numpy as jnp, numpy as np
+        from repro import obs
+        from repro.core.codes import make_code
+        from repro.dist import collectives
+        from repro.kernels import ops
+        from repro.launch.mesh import make_repair_mesh
+
+        # the kernel path off a TPU: interpret mode, whose values the vma
+        # check cannot follow (the compiled kernel is not affected)
+        collectives.gf_path = lambda mesh: 'pallas'
+        ops.gf_product = lambda masks, operands: ops.gf_matmul_pallas(
+            masks, operands, block_b=512, interpret=True)
+        jax.shard_map = functools.partial(jax.shard_map, check_vma=False)
+        code = make_code('{family}', {n}, {k}, {r})
+        mesh = make_repair_mesh({r}, {n // r}, jax.devices()[:{devices}])
+        rng = np.random.default_rng({devices})
+        stripes = [code.encode(rng.integers(
+            0, 256, (code.k * code.alpha, 640), dtype=np.uint8))
+            for _ in range(2)]
+        x = jnp.asarray(np.stack([np.stack(s) for s in stripes]))
+        with obs.tracing('t') as tr:
+            out, specs = collectives.spmd_node_recovery(code, 0, x, mesh)
+        out = np.asarray(out)
+        for s, sp in enumerate(specs):
+            row = sp.target_pod * sp.w
+            want = code.repair_plan(0, rotation=s).execute(
+                {{i: p for i, p in enumerate(stripes[s]) if i != 0}})
+            assert np.array_equal(out[s, row], want), s
+            assert not np.delete(out[s], row, axis=0).any(), s
+        counts = collectives.GfCounts.of(specs, *mesh.devices.shape)
+        for stage in counts.products:
+            assert tr.counter_value('repair.gf_kernel_calls', stage=stage
+                                    ) == counts.products[stage], stage
+            assert tr.counter_value('repair.gf_rows_in_place', stage=stage
+                                    ) == counts.rows_in_place[stage], stage
+        print('COUNTS', sum(counts.products.values()),
+              sum(counts.rows_in_place.values()))
+        """,
+        devices=devices,
+    )
+    line = next(l for l in out.splitlines() if l.startswith("COUNTS"))
+    assert list(map(int, line.split()[1:])) == [kernels, rows]
 
 
 @pytest.mark.parametrize("family, missing", [("DRC", set()),

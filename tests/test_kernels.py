@@ -127,6 +127,75 @@ def test_stacked_bitmatrix_indexed_by_traced_node():
     assert pick._cache_size() == 1
 
 
+# Operand references, as the repair program hands them to the kernel:
+# (source shapes, then per reference its source, leading indices and
+# picked rows), and the tile.  Sources of 1, 3 and 27 rows; picks out of
+# order and repeated; one source referenced twice; a ragged last tile.
+OPERAND_CASES = {
+    "rows_1_3_27": ([(1, 128 * 8), (2, 9, 3, 128 * 8), (27, 128 * 8)],
+                    [(0, (), (0,)), (1, (1, 4), (2, 0, 1)),
+                     (2, (), (26, 3, 9, 0))], 512),
+    "out_of_order_repeated": ([(3, 2, 3, 128 * 4), (3, 128 * 4)],
+                              [(0, (2, 1), (2, 2, 0)), (1, (), (1,)),
+                               (0, (0, 1), (1,)), (1, (), (0, 2))], 512),
+    "ragged_last_tile": ([(2, 9, 27, 128 * 11), (3, 128 * 11)],
+                         [(0, (1, 8), (8, 17, 26, 0)), (1, (), (2, 1))],
+                         1024),
+}
+
+
+@pytest.mark.parametrize("case", [*OPERAND_CASES, "traced_lead"])
+def test_operand_references_match_jnp_on_the_picks(case):
+    """The kernel reading its K rows where they lie, through one
+    BlockSpec a reference with the leading indices as scalar-prefetch
+    data, gives the bytes of ``gf_matmul_jnp`` over the picked rows
+    concatenated; a traced leading index serves every stripe and node
+    from one compiled kernel."""
+    import jax
+
+    from repro.core.gf_jax import gf_matmul_jnp
+    from repro.kernels.gf_matmul import Operand
+
+    rng = np.random.default_rng(len(case))
+    if case == "traced_lead":
+        shapes, refs, tile = [(3, 9, 3, 128 * 5)], [(0, None, (2, 0))], 512
+    else:
+        shapes, refs, tile = OPERAND_CASES[case]
+    srcs = [rng.integers(0, 256, size=sh, dtype=np.uint8) for sh in shapes]
+    k = sum(len(rows) for _, _, rows in refs)
+    m = rng.integers(0, 256, size=(5, k), dtype=np.uint8)
+    masks = jnp.asarray(bit_expand(m))
+
+    def picked(lead_of):
+        return np.concatenate([
+            srcs[i][lead_of(lead)][list(rows)] for i, lead, rows in refs])
+
+    def want(lead_of):
+        return np.asarray(gf_matmul_jnp(jnp.asarray(m),
+                                        jnp.asarray(picked(lead_of))))
+
+    if case != "traced_lead":
+        got = gf_matmul_pallas(masks, [
+            Operand(jnp.asarray(srcs[i]), lead, rows)
+            for i, lead, rows in refs], block_b=tile, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), want(tuple))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      gfnp.gf_matmul(m, picked(tuple)))
+        return
+
+    @jax.jit
+    def at(s, node, x):
+        return gf_matmul_pallas(masks, [Operand(x, (s, node), (2, 0))],
+                                block_b=tile, interpret=True)
+
+    x = jnp.asarray(srcs[0])
+    for s, node in [(0, 0), (2, 8), (1, 4)]:
+        got = at(jnp.int32(s), jnp.int32(node), x)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      want(lambda _: (s, node)))
+    assert at._cache_size() == 1
+
+
 def test_small_payload_fallback():
     rng = np.random.default_rng(6)
     m, x = _rand(rng, 3, 6, 17)
@@ -173,13 +242,14 @@ def test_encode_payload_systematic():
 
 def test_choose_block_b_bounds():
     from repro.kernels.gf_matmul import WORD_TILE
-    from repro.kernels.ops import MAX_TILE, STEP_BYTES
+    from repro.kernels.ops import MAX_TILE, MIN_TILE, STEP_BYTES
 
     for k, r in [(1, 1), (18, 27), (162, 27), (512, 64), (4096, 4096),
+                 (216, 27),  # MSR(9,6,3) Decode: 8 whole helper blocks
                  *[(k, r) for r, k in CELL_PRODUCTS + MSR_PRODUCTS]]:
         tb = choose_block_b(k, r)
-        assert tb % WORD_TILE == 0 and WORD_TILE <= tb <= MAX_TILE
-        assert tb * (k + r) <= STEP_BYTES or tb == WORD_TILE
+        assert tb % WORD_TILE == 0 and MIN_TILE <= tb <= MAX_TILE
+        assert tb * (k + r) <= STEP_BYTES or tb == MIN_TILE
         assert tb == MAX_TILE or 2 * tb * (k + r) > STEP_BYTES
 
 

@@ -228,7 +228,7 @@ def test_kernel_geometry_rejects_indivisible_payload():
     bounds and write-disjoint; a tile that is no multiple of 512 bytes
     (four lane-rows of 32-bit words) is still refused."""
     geom = gf_matmul_geometry(3, 6, 1000, 512)
-    assert geom.grid == (2,) and geom.clipped_dim == 1
+    assert geom.grid == (2,) and geom.clipped_dim == -1
     assert pallas.analyze_geometry(geom) == []
     with pytest.raises(ValueError, match="not a multiple"):
         gf_matmul_geometry(3, 6, 1000, 384)
@@ -236,6 +236,43 @@ def test_kernel_geometry_rejects_indivisible_payload():
     geom = gf_matmul_geometry(3, 6, 200, 4096)
     assert geom.out_block == (3, 512) and geom.grid == (1,)
     assert pallas.analyze_geometry(geom) == []
+
+
+@pytest.mark.parametrize("shape", list(pallas.OPERAND_SHAPES))
+def test_operand_geometry_in_bounds_and_write_disjoint(shape):
+    """Each cell's products as operand references: one BlockSpec a
+    source, its leading axes squeezed at the prefetched indices, swept
+    at the last stripe and node they read, and tiled as the kernel tiles
+    them for the whole source blocks a step moves."""
+    from repro.kernels.gf_matmul import operand_geometry
+    from repro.kernels.ops import choose_block_b
+
+    r, k, sources, b, tile = shape
+    geom = operand_geometry(*shape)
+    assert len(geom.in_specs()) == 1 + len(sources)
+    assert geom.prefetch == tuple(i for _, lead in sources for i in lead)
+    assert tile == choose_block_b(sum(sh[-2] for sh, _ in sources), r)
+    assert pallas.analyze_geometry(geom) == []
+
+
+def test_operand_geometry_lead_past_its_axis_is_caught():
+    """A leading index one past its axis, or an index map that reads the
+    prefetch vector one slot on, puts a squeezed block outside its
+    source: the oob rule finds it, the alias rule does not."""
+    from repro.kernels.gf_matmul import operand_geometry
+
+    x = (3, 9, 3, 4096)
+    geom = operand_geometry(3, 6, ((x, (2, 8)), (x, (1, 4))), 4096, 512)
+    assert pallas.analyze_geometry(geom) == []
+    past = operand_geometry(3, 6, ((x, (2, 9)), (x, (1, 4))), 4096, 512)
+    findings = pallas.analyze_geometry(past)
+    assert {f.rule for f in findings} == {pallas.R_PL_OOB}
+    assert findings[0].witness["dim"] == 1 and findings[0].witness["start"] == 9
+    maps = list(geom.in_index_maps)
+    maps[1] = lambda j, lead: (lead[1], lead[2], 0, j)
+    findings = pallas.check_pallas_oob(
+        dataclasses.replace(geom, in_index_maps=tuple(maps)))
+    assert findings and findings[0].witness["start"] == 8  # lead[1] as a stripe
 
 
 def test_geometry_sweep_tiles_the_cells_as_the_kernel_does():
